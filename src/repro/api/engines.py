@@ -18,12 +18,60 @@ def _grad_builder(spec, cfg, opt, policy):
     a specific ExecutionPolicy backend."""
     from repro.core import mesp
 
+    def vag(params, batch):
+        return mesp.value_and_grad(params, cfg, batch, policy=policy)
+
+    if policy.backend == "pallas":
+        vag = _kernel_data_parallel(vag, cfg, policy)
+
     def step(params, opt_state, batch):
-        loss, grads = mesp.value_and_grad(params, cfg, batch, policy=policy)
+        loss, grads = vag(params, batch)
         params, opt_state = opt.update(grads, opt_state, params)
         return params, opt_state, loss
 
     return step
+
+
+def _kernel_data_parallel(vag, cfg, policy):
+    """Data parallelism for the Pallas backend. XLA cannot partition a
+    Mosaic kernel, so under a mesh whose ``model`` axis is 1 the
+    value-and-grad runs per batch shard inside a ``shard_map`` and the loss
+    and grads are averaged over the data axes (the gradient all-reduce).
+
+    The batch layout is the Trainer's: ``policy.act_spec[0]`` names the
+    data axes it splits the batch over, or is None when every device holds
+    the whole batch. Without an ``act_spec`` (no mesh) or under model
+    parallelism (which the kernels do not support on a TPU) ``vag`` runs
+    as is."""
+    if policy.act_spec is None:
+        return vag
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import mesp
+    from repro.models.layers import ambient_mesh
+
+    bdim = policy.act_spec[0]
+    # activation constraints name mesh axes, which are manual in the body
+    local_policy = policy.with_(act_spec=None)
+
+    def run(params, batch):
+        mesh = ambient_mesh()
+        if mesh is None or mesh.shape.get("model", 1) != 1:
+            return vag(params, batch)
+
+        def shard(params, batch):
+            out = mesp.value_and_grad(params, cfg, batch, policy=local_policy)
+            return jax.lax.pmean(out, bdim) if bdim is not None else out
+
+        # check_vma off: pallas_call outputs carry no varying-axes info;
+        # every output here is replicated
+        return jax.shard_map(shard, mesh=mesh, in_specs=(P(), P(bdim)),
+                             out_specs=(P(), P()),
+                             check_vma=False)(params, batch)
+
+    return run
 
 
 def _grad_vag(params, cfg, batch, *, policy, key=None):

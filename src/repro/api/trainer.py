@@ -16,11 +16,13 @@ while carrying the optimizer state across compatible transitions.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 from typing import Any, Callable, List, Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.api.registry import Engine, get_engine
 from repro.api.spec import TrainSpec
@@ -212,6 +214,7 @@ class Trainer:
             jitted = jax.jit(
                 build, in_shardings=(pshard, oshard, bshard),
                 out_shardings=(pshard, oshard, NamedSharding(mesh, P())))
+            self._state_shardings = (pshard, oshard)
 
             def step_fn(params, opt_state, batch, _j=jitted, _m=mesh):
                 with _m:
@@ -221,12 +224,44 @@ class Trainer:
         #: the raw jitted step (no mesh-context wrapper) — ``.lower()`` this
         #: for compiled-HLO inspection (fleet collective-bytes checks)
         self._jit_step = jitted
+        self._compiled = None
         self._live_spec = spec
 
     @property
     def live_spec(self) -> TrainSpec:
         """The spec currently compiled (post-degradation, if any)."""
         return self._live_spec or self.spec
+
+    def batch_struct(self):
+        """ShapeDtypeStructs of one batch as :meth:`make_data` yields it for
+        the live spec."""
+        live = self.live_spec
+        s = jax.ShapeDtypeStruct((live.batch, live.seq), jnp.int32)
+        return {"tokens": s, "labels": s}
+
+    def compile_step(self):
+        """Compile the live spec's step for its state and batch shapes and
+        return the ``Compiled`` program (kept until the next spec switch).
+        The first real step then reuses this executable, and a compile
+        error — never transient — raises here instead of inside the
+        resilient loop's retry and OOM handling."""
+        if self._compiled is None:
+            pstruct, ostruct = self._state_struct(self.live_spec)
+            if self.mesh is not None:
+                # placed as init_state places the state and as the step
+                # returns it, so no step call traces the step again
+                def put(s, sharding):
+                    return jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                sharding=sharding)
+
+                pshard, oshard = self._state_shardings
+                pstruct = jax.tree_util.tree_map(put, pstruct, pshard)
+                ostruct = jax.tree_util.tree_map(put, ostruct, oshard)
+            with (self.mesh if self.mesh is not None
+                  else contextlib.nullcontext()):
+                self._compiled = self._jit_step.lower(
+                    pstruct, ostruct, self.batch_struct()).compile()
+        return self._compiled
 
     # ---------------------------------------------------------------- state
     def init_state(self):
@@ -236,7 +271,7 @@ class Trainer:
         params = model_lib.init_params(
             jax.random.PRNGKey(self.spec.seed), self.cfg,
             quantize=live.quantize)
-        return params, self.opt.init(params)
+        return self.shard_state(params, self.opt.init(params))
 
     def make_data(self, state=None):
         from repro.data import make_batch_iterator
@@ -268,6 +303,15 @@ class Trainer:
         spec0 = self.spec
         total = steps if steps is not None else spec0.steps
         self._switch_to(spec0)
+        try:
+            self.compile_step()
+        except Exception as e:
+            # a compile error raises here, except an OOM with the ladder on:
+            # the first step then raises it again inside the loop, which
+            # hands it to the ladder (on_oom)
+            if spec0.degrade != "on" or not faults_mod.is_oom_error(e):
+                raise
+            log.warning("step does not fit at compile: %s", e)
         ckpt = Checkpointer(spec0.ckpt_dir, interval=spec0.ckpt_interval)
 
         tel = telemetry if telemetry is not None \
@@ -377,8 +421,19 @@ class Trainer:
                                          state=new_it.state)
                 try:
                     self._switch_to(cand)
-                except Exception as e:
+                except (ValueError, KeyError) as e:   # engine refuses it
                     log.debug("rung %s unbuildable: %s", rung, e)
+                    continue
+                try:
+                    self.compile_step()
+                except Exception as e:
+                    self._switch_to(live)
+                    # a rung that does not fit either is skipped; any other
+                    # compile error is a fault of the program, not pressure
+                    if not faults_mod.is_oom_error(e):
+                        raise
+                    log.warning("rung %s does not fit at compile: %s",
+                                rung, e)
                     continue
                 params, opt_state = loop.params, loop.opt_state
                 if cand.quantize != live.quantize:

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import (block_for, flash_schedule,
+from repro.kernels.tiling import (LANE, block_for, flash_schedule,
                                   flash_schedule_kv, pad_dim)
 
 NEG_INF = -1e30
@@ -71,6 +71,15 @@ def _rot(x, cos, sin):
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
+
+
+def _lanes(r):
+    """[BH, N] per-row f32 -> [BH, N, LANE] lane-replicated: the kernels'
+    layout for lse/delta. A (bq, LANE) block satisfies the TPU's (8, 128)
+    tiling, and slicing lane 0 inside the kernel gives the (bq, 1) column a
+    score tile broadcasts against, with no sublane<->lane transpose. Only
+    the compact [BH, N] form is kept as a residual between fwd and bwd."""
+    return jnp.broadcast_to(r[..., None], r.shape + (LANE,))
 
 
 def _pad_table(t, mult: int, value: float):
@@ -152,8 +161,8 @@ def _fwd_kernel(qi_ref, kj_ref, int_ref, q_ref, k_ref, v_ref, *rest,
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = jnp.where(never, 0.0,
                              acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(never[:, 0], NEG_INF,
-                               (m_ref[...] + jnp.log(l))[:, 0])
+        lse = jnp.where(never, NEG_INF, m_ref[...] + jnp.log(l))
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 @functools.lru_cache(maxsize=None)
@@ -193,7 +202,8 @@ def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
             out_specs=[
                 pl.BlockSpec((1, bq, D),
                              lambda b, t, qi, kj, it: (b, qi[t], 0)),
-                pl.BlockSpec((1, bq), lambda b, t, qi, kj, it: (b, qi[t])),
+                pl.BlockSpec((1, bq, LANE),
+                             lambda b, t, qi, kj, it: (b, qi[t], 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, 1), jnp.float32),   # running max
@@ -203,8 +213,9 @@ def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Nqp, D), dtype),
-            jax.ShapeDtypeStruct((BH, Nqp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Nqp, LANE), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )
     return call, (qi, kj, it)
@@ -248,7 +259,7 @@ def flash_attention_fwd(q, k, v, rope=None, *, causal: bool = True,
     out, lse = call(*sched, *operands)
     out = out[:, :Nq]
     if return_lse:
-        return out, lse[:, :Nq]
+        return out, lse[:, :Nq, 0]
     return out
 
 
@@ -287,7 +298,7 @@ def _bwd_dq_kernel(qi_ref, kj_ref, int_ref, q_ref, k_ref, v_ref, g_ref,
     def _accum(p):
         dp = jax.lax.dot_general(gb, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # eq 18
-        ds = p * (dp - delta_ref[0][:, None]) * scale                 # eq 19
+        ds = p * (dp - delta_ref[0][:, :1]) * scale                   # eq 19
         acc_ref[...] += jax.lax.dot(ds.astype(qb.dtype), kb,
                                     preferred_element_type=jnp.float32)
 
@@ -295,7 +306,7 @@ def _bwd_dq_kernel(qi_ref, kj_ref, int_ref, q_ref, k_ref, v_ref, g_ref,
 
     @pl.when(interior)
     def _interior():
-        _accum(jnp.exp(s - lse_ref[0][:, None]))
+        _accum(jnp.exp(s - lse_ref[0][:, :1]))
 
     @pl.when(jnp.logical_not(interior))
     def _boundary():
@@ -305,7 +316,7 @@ def _bwd_dq_kernel(qi_ref, kj_ref, int_ref, q_ref, k_ref, v_ref, g_ref,
                    nq=nq_valid, nk=nk_valid)
         # p via saved lse; explicit zero on masked/padded entries (a fully-
         # masked row has lse = NEG_INF, where exp(s − lse) would blow up)
-        _accum(jnp.where(ok, jnp.exp(s - lse_ref[0][:, None]), 0.0))
+        _accum(jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0))
 
     @pl.when(last)
     def _finish():
@@ -350,7 +361,7 @@ def _bwd_dkv_kernel(kjs_ref, gh_ref, qis_ref, int_ref, q_ref, g_ref, lse_ref,
                                            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(gb, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # eq 18
-        ds = (p * (dp - delta_ref[0][:, None]) * scale).astype(qb.dtype)
+        ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(qb.dtype)
         # dk += dsᵀ q  (eq 21)
         dk_acc[...] += jax.lax.dot_general(ds, qb, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
@@ -359,7 +370,7 @@ def _bwd_dkv_kernel(kjs_ref, gh_ref, qis_ref, int_ref, q_ref, g_ref, lse_ref,
 
     @pl.when(interior)
     def _interior():
-        _accum(jnp.exp(s - lse_ref[0][:, None]))
+        _accum(jnp.exp(s - lse_ref[0][:, :1]))
 
     @pl.when(jnp.logical_not(interior))
     def _boundary():
@@ -367,7 +378,7 @@ def _bwd_dkv_kernel(kjs_ref, gh_ref, qis_ref, int_ref, q_ref, g_ref, lse_ref,
         k_pos = col * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         ok = _mask(q_pos, k_pos, causal=causal, window=window,
                    nq=nq_valid, nk=nk_valid)
-        _accum(jnp.where(ok, jnp.exp(s - lse_ref[0][:, None]), 0.0))
+        _accum(jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0))
 
     @pl.when(last)
     def _finish():
@@ -398,8 +409,10 @@ def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str,
         pl.BlockSpec((1, bk, D),
                      lambda b, t, qi, kj, it: (b // G, kj[t], 0)),   # v
         pl.BlockSpec((1, bq, D), lambda b, t, qi, kj, it: (b, qi[t], 0)),  # g
-        pl.BlockSpec((1, bq), lambda b, t, qi, kj, it: (b, qi[t])),  # lse
-        pl.BlockSpec((1, bq), lambda b, t, qi, kj, it: (b, qi[t])),  # delta
+        pl.BlockSpec((1, bq, LANE),
+                     lambda b, t, qi, kj, it: (b, qi[t], 0)),        # lse
+        pl.BlockSpec((1, bq, LANE),
+                     lambda b, t, qi, kj, it: (b, qi[t], 0)),        # delta
     ]
     if fuse_rope:
         in_specs += [
@@ -419,6 +432,7 @@ def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((BH, Nqp, D), dtype),
+        name="flash_dq",
         interpret=interpret,
     )
     return call, (qi, kj, it)
@@ -437,13 +451,13 @@ def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, dtype_q: str,
         nq_valid=nq, nk_valid=nk, scale=float(1.0 / (D ** 0.5)),
         fuse_rope=fuse_rope)
     qmap = lambda b, t, kjs, gh, qis, it: (b * G + gh[t], qis[t], 0)
-    rmap = lambda b, t, kjs, gh, qis, it: (b * G + gh[t], qis[t])
+    rmap = lambda b, t, kjs, gh, qis, it: (b * G + gh[t], qis[t], 0)
     kvmap = lambda b, t, kjs, gh, qis, it: (b, kjs[t], 0)
     in_specs = [
         pl.BlockSpec((1, bq, D), qmap),        # q
         pl.BlockSpec((1, bq, D), qmap),        # g
-        pl.BlockSpec((1, bq), rmap),           # lse
-        pl.BlockSpec((1, bq), rmap),           # delta
+        pl.BlockSpec((1, bq, LANE), rmap),     # lse
+        pl.BlockSpec((1, bq, LANE), rmap),     # delta
         pl.BlockSpec((1, bk, D), kvmap),       # k
         pl.BlockSpec((1, bk, D), kvmap),       # v
     ]
@@ -477,6 +491,7 @@ def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, dtype_q: str,
             jax.ShapeDtypeStruct((BHkv, Nkp, D), jnp.dtype(dtype_k)),
             jax.ShapeDtypeStruct((BHkv, Nkp, D), jnp.dtype(dtype_v)),
         ],
+        name="flash_dkv",
         interpret=interpret,
     )
     return call, (kjs, gh, qis, it)
@@ -508,8 +523,8 @@ def flash_attention_bwd(q, k, v, out, lse, g, rope=None, *,
 
     qp = pad_dim(q, bq, 1)
     gp = pad_dim(g.astype(q.dtype), bq, 1)
-    lsep = pad_dim(lse, bq, 1)
-    deltap = pad_dim(delta, bq, 1)
+    lsep = _lanes(pad_dim(lse, bq, 1))
+    deltap = _lanes(pad_dim(delta, bq, 1))
     kp = pad_dim(k, bk, 1)
     vp = pad_dim(v, bk, 1)
     Nqp, Nkp = qp.shape[1], kp.shape[1]

@@ -78,6 +78,7 @@ def _lora_fused_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
             pltpu.VMEM((bm, bn), jnp.float32),                # W0 accumulator
             pltpu.VMEM((bm, r), jnp.float32),                 # h tile (VMEM!)
         ],
+        name="lora_fwd",
         interpret=interpret,
     )
 
@@ -137,6 +138,7 @@ def _lora_dx_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, n: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Kp), jnp.dtype(dtype_name)),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        name="lora_dx",
         interpret=interpret,
     )
 
@@ -217,6 +219,7 @@ def _lora_dab_call(Mp: int, Kp: int, Np: int, r: int, scale: float, bm: int,
             jax.ShapeDtypeStruct((Kp, r), jnp.float32),
             jax.ShapeDtypeStruct((r, Np), jnp.float32),
         ],
+        name="lora_dab",
         interpret=interpret,
     )
 

@@ -174,6 +174,7 @@ def _grouped_fwd_call(Mp: int, Kp: int, Np: int, Ew: int, E: int, r: int,
         functools.partial(kern, scale=scale, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.dtype(dtype_name)),
+        name="lora_grouped_fwd",
         interpret=interpret,
     )
 
@@ -354,6 +355,7 @@ def _grouped_dx_call(Mp: int, Kp: int, Np: int, Ew: int, E: int, r: int,
         functools.partial(kern, n_n=n_n),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Kp), jnp.dtype(dtype_name)),
+        name="lora_grouped_dx",
         interpret=interpret,
     )
 
@@ -493,6 +495,7 @@ def _grouped_dab_call(Mp: int, Kp: int, Np: int, E: int, r: int,
             jax.ShapeDtypeStruct((E, Kp, r), jnp.float32),
             jax.ShapeDtypeStruct((E, r, Np), jnp.float32),
         ],
+        name="lora_grouped_dab",
         interpret=interpret,
     )
 

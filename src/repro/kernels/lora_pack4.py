@@ -10,9 +10,10 @@ exists only tile-by-tile inside VMEM, never as an HBM array.
 Per K-tile the VPU unpacks a ``[bk/2, bn]`` byte block into a ``[bk, bn]``
 value block in front of the MXU:
 
-* both formats: ``lo = v & 0xF``, ``hi = v >> 4``, interleaved back to input
-  order (byte row j holds input rows 2j/2j+1) by a stack+reshape that keeps
-  the lane (N) dimension intact;
+* both formats: ``lo = v & 0xF``, ``hi = v >> 4``, each decoded to f32 in
+  the byte tile's layout, then interleaved back to input order (byte row j
+  holds input rows 2j/2j+1) by a stack+reshape that keeps the lane (N)
+  dimension intact;
 * ``int4``: two's-complement sign extension ``(nib ^ 8) - 8``;
 * ``nf4``: a 16-entry codebook lookup, compiled as a chain of 16 vector
   selects against the static :data:`repro.core.quant.NF4_CODE` constants (no
@@ -51,21 +52,29 @@ from repro.core.quant import NF4_CODE
 from repro.kernels.tiling import block_for, pad_dim
 
 
+def _decode(nib, method: str):
+    """int32 nibbles (0..15) -> f32 values, elementwise."""
+    if method == "int4":
+        return ((nib ^ 8) - 8).astype(jnp.float32)
+    # nf4: 16-entry codebook gather as a static select chain on the VPU
+    w = jnp.full(nib.shape, NF4_CODE[0], jnp.float32)
+    for i in range(1, 16):
+        w = jnp.where(nib == i, jnp.float32(NF4_CODE[i]), w)
+    return w
+
+
 def _unpack_tile(packed, method: str, dtype):
     """uint8 [bk/2, bn] byte tile -> [bk, bn] dequantized-value tile (no
     scale — that is hoisted out of the K-sum by the caller)."""
     v = packed.astype(jnp.int32)
-    lo, hi = v & 0xF, v >> 4
+    # decode each nibble plane in the byte tile's own layout: comparing the
+    # interleaved array instead makes Mosaic relayout its i1 select masks,
+    # which it refuses for the nf4 chain
+    lo, hi = _decode(v & 0xF, method), _decode(v >> 4, method)
     # interleave to input order: row 2j <- lo[j], row 2j+1 <- hi[j]. The
     # reshape merges the sublane axes only; the lane (N) axis is untouched.
-    nib = jnp.stack([lo, hi], axis=1).reshape(2 * v.shape[0], v.shape[1])
-    if method == "int4":
-        return ((nib ^ 8) - 8).astype(dtype)
-    # nf4: 16-entry codebook gather as a static select chain on the VPU
-    w = jnp.full(nib.shape, NF4_CODE[0], dtype)
-    for i in range(1, 16):
-        w = jnp.where(nib == i, jnp.asarray(NF4_CODE[i], dtype), w)
-    return w
+    return jnp.stack([lo, hi], axis=1).reshape(
+        2 * v.shape[0], v.shape[1]).astype(dtype)
 
 
 def _lora_fused_q4_kernel(x_ref, q4_ref, s_ref, a_ref, b_ref, o_ref,
@@ -116,6 +125,7 @@ def _lora_fused_q4_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
             pltpu.VMEM((bm, bn), jnp.float32),                  # W0 accum
             pltpu.VMEM((bm, r), jnp.float32),                   # h tile
         ],
+        name="lora_q4_fwd",
         interpret=interpret,
     )
 
@@ -188,6 +198,7 @@ def _lora_dx_q4_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, n: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Kp), jnp.dtype(dtype_name)),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        name="lora_q4_dx",
         interpret=interpret,
     )
 

@@ -84,6 +84,7 @@ def _lora_fused_q_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
             pltpu.VMEM((bm, bn), jnp.float32),                # W0 accumulator
             pltpu.VMEM((bm, r), jnp.float32),                 # h tile (VMEM!)
         ],
+        name="lora_q_fwd",
         interpret=interpret,
     )
 
@@ -149,6 +150,7 @@ def _lora_dx_q_call(Mp: int, Kp: int, Np: int, r: int, dtype_name: str,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, n: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Kp), jnp.dtype(dtype_name)),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        name="lora_q_dx",
         interpret=interpret,
     )
 

@@ -13,8 +13,9 @@ ExecutionPolicy backend.
 * picks block sizes from ``kernels/autotune.py`` (heuristic table, optionally
   overridden by a measured cache);
 * runs the Pallas kernel with ``interpret=True`` automatically on non-TPU
-  backends (override with ``REPRO_PALLAS_INTERPRET=0/1``), so the same
-  training code runs on CPU tests and TPU production.
+  backends, so the same training code runs on CPU tests and TPU production.
+  On a TPU the interpreter runs only when ``ExecutionPolicy.interpret``
+  (``--pallas-interpret on``) asks for it explicitly.
 
 The custom_vjps below compose the kernel forwards with kernel backwards that
 follow the paper's structured rules: ``h``/probabilities are *recomputed* in
@@ -23,7 +24,6 @@ the backward (from ``x`` / the saved logsumexp), never stored.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -52,9 +52,6 @@ def _flat(x):
 
 def pallas_interpret() -> bool:
     """True when kernels must run under the Pallas interpreter (non-TPU)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 
@@ -427,8 +424,13 @@ def lora_grouped_decode(x, w0, a, b, tile_gid, bias=None, scale: float = 2.0,
         w0e = (quant.add_group_axis(w0)
                if quant.is_packed(w0) or quant.is_quantized(w0)
                else w0[None])
-        y = _grouped_dispatch(x, w0e, a, b, tile_gid, scale, bm,
-                              _resolve_interpret(policy, interpret))
+        # the kernel's row block must be a multiple of 8 sublanes on TPU:
+        # pad every slot tile with zero rows up to that and slice them off
+        bmp = tiling.ceil_to(bm, 8)
+        xp = tiling.pad_dim(x.reshape(M // bm, bm, K), bmp, 1)
+        y = _grouped_dispatch(xp.reshape(-1, K), w0e, a, b, tile_gid, scale,
+                              bmp, _resolve_interpret(policy, interpret))
+        y = y.reshape(M // bm, bmp, -1)[:, :bm].reshape(M, -1)
     else:
         row_gid = jnp.repeat(jnp.asarray(tile_gid, jnp.int32), bm)
         w = quant.maybe_dequant(w0, x.dtype)

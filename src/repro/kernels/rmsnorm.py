@@ -40,6 +40,7 @@ def rmsnorm(x, w, eps: float = 1e-6, *, bm: int = 256,
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, d), x.dtype),
+        name="rmsnorm_fwd",
         interpret=interpret,
     )(xp, w.reshape(1, d))
     return out[:M]
@@ -54,7 +55,7 @@ def _rmsnorm_bwd_kernel(x_ref, w_ref, g_ref, dx_ref, dwp_ref, *, eps: float):
     dxhat = g * w
     dx = (dxhat - xhat * jnp.mean(dxhat * xhat, -1, keepdims=True)) * rms
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    dwp_ref[...] = jnp.sum(g * xhat, 0, keepdims=True)
+    dwp_ref[0] = jnp.sum(g * xhat, 0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bm", "interpret"))
@@ -77,12 +78,16 @@ def rmsnorm_bwd(x, w, g, eps: float = 1e-6, *, bm: int = 256,
         ],
         out_specs=[
             pl.BlockSpec((bm, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            # one [1, d] partial per row block: a (1, d) block of a
+            # [Mp/bm, 1, d] array spans the last two dims whole, which the
+            # TPU's (8, 128) tiling needs when there is more than one block
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Mp, d), x.dtype),
-            jax.ShapeDtypeStruct((Mp // bm, d), jnp.float32),
+            jax.ShapeDtypeStruct((Mp // bm, 1, d), jnp.float32),
         ],
+        name="rmsnorm_bwd",
         interpret=interpret,
     )(xp, w.reshape(1, d), gp)
-    return dx[:M], jnp.sum(dwp, 0).astype(w.dtype)
+    return dx[:M], jnp.sum(dwp, (0, 1)).astype(w.dtype)

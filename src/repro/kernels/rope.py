@@ -84,6 +84,7 @@ def _rope_call(B: int, Np: int, H: int, D: int, dtype_name: str, bn: int,
         ],
         out_specs=pl.BlockSpec((1, bn, H, D), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Np, H, D), jnp.dtype(dtype_name)),
+        name="rope",
         interpret=interpret,
     )
 

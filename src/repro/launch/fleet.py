@@ -53,7 +53,6 @@ def fleet_env(devices: int, env: Optional[dict] = None) -> dict:
     only the device-count flag is replaced."""
     env = dict(os.environ if env is None else env)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("REPRO_PALLAS_INTERPRET", "1")
     force_host_device_count(devices, env=env)
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -151,15 +150,6 @@ def _make_trainer(payload: dict):
     return Trainer.from_spec(spec)
 
 
-def _batch_struct(tr):
-    import jax
-    import numpy as np
-
-    live = tr.live_spec
-    s = jax.ShapeDtypeStruct((live.batch, live.seq), np.int32)
-    return {"tokens": s, "labels": s}
-
-
 def _worker_telemetry(payload: dict):
     """Per-worker Telemetry writing a ``worker_<id>.jsonl`` shard when the
     payload carries ``telemetry_dir`` (parent merges shards afterwards with
@@ -228,8 +218,6 @@ def task_train(payload: dict) -> dict:
 
 
 def task_collectives(payload: dict) -> dict:
-    import contextlib
-
     import jax
 
     from repro.models.model import split_params
@@ -237,12 +225,8 @@ def task_collectives(payload: dict) -> dict:
                                          predicted_grad_sync_bytes)
 
     tr = _make_trainer(payload)
-    pstruct, ostruct = tr._state_struct(tr.live_spec)
-    ctx = tr.mesh if tr.mesh is not None else contextlib.nullcontext()
-    with ctx:
-        txt = tr._jit_step.lower(pstruct, ostruct,
-                                 _batch_struct(tr)).compile().as_text()
-    coll = collective_bytes(txt)
+    pstruct, _ = tr._state_struct(tr.live_spec)
+    coll = collective_bytes(tr.compile_step().as_text())
     train, _ = split_params(pstruct)
     leaves = jax.tree_util.tree_leaves(train)
     n_trainable = sum(l.size for l in leaves)

@@ -39,6 +39,7 @@ import numpy as np
 from repro.api import ExecutionPolicy, TrainSpec, build_arg_parser
 from repro.configs import get_config
 from repro.core import quant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serve import (AdapterStore, ContinuousBatcher, Request,
                          synthetic_adapters)
@@ -163,6 +164,7 @@ def main(argv=None):
     ns = ap.parse_args(argv)
     spec = TrainSpec.from_namespace(ns).validate()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = get_config(spec.arch)
     if spec.reduced:

@@ -19,6 +19,7 @@ import logging
 
 from repro import telemetry
 from repro.api import Trainer, TrainSpec
+from repro.launch.compile_cache import enable_compile_cache
 # re-exported: scripts/check_readme_flags.py and tests import the parser
 # from here, its historical home
 from repro.api import build_arg_parser  # noqa: F401
@@ -28,6 +29,7 @@ log = logging.getLogger("repro.train")
 
 def main(argv=None):
     spec = TrainSpec.from_cli_args(argv).validate()
+    enable_compile_cache()
 
     logging.basicConfig(
         level=logging.WARNING if spec.quiet else logging.INFO)

@@ -44,21 +44,28 @@ def _split(key, n):
     return jax.random.split(key, n)
 
 
-def mesh_axis_size(axis) -> int:
-    """Size of a physical-mesh axis (or axis tuple) at trace time; 1 when no
-    mesh context is installed (unit tests).
+def ambient_mesh():
+    """The physical mesh installed by ``with mesh:`` at trace time, or
+    ``None`` outside any mesh context (unit tests, single-device runs).
 
-    Reads the mesh context installed by ``with mesh:`` via the public
-    ``jax.interpreters.pxla.thread_resources`` handle (the supported
-    spelling of the old ``jax._src.mesh`` probe).
+    Reads it via the public ``jax.interpreters.pxla.thread_resources``
+    handle (the supported spelling of the old ``jax._src.mesh`` probe).
     """
-    if axis is None:
-        return 1
     try:
         from jax.interpreters import pxla
         mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return 1
+    except Exception:
+        return None
+    return None if mesh.empty else mesh
+
+
+def mesh_axis_size(axis) -> int:
+    """Size of a physical-mesh axis (or axis tuple) at trace time; 1 when no
+    mesh context is installed (unit tests)."""
+    mesh = ambient_mesh()
+    if axis is None or mesh is None:
+        return 1
+    try:
         if isinstance(axis, (tuple, list)):
             n = 1
             for a in axis:

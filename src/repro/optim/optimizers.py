@@ -35,8 +35,11 @@ def sgd(lr: float | Callable[[jax.Array], jax.Array]) -> Optimizer:
     def update(grads, state, params):
         step = state["step"] + 1
         lr_t = lr(step) if callable(lr) else lr
+        # update in f32, store in the param's dtype: a bf16 param that came
+        # back f32 would change the step's input types and force a retrace
         new = _map(lambda p, g: p if g is None else
-                   (p - lr_t * g.astype(p.dtype)), params, grads)
+                   (p - lr_t * g.astype(jnp.float32)).astype(p.dtype),
+                   params, grads)
         return new, {"step": step}
 
     return Optimizer(init, update)

@@ -8,18 +8,19 @@ Three terms per (arch × shape × mesh), in seconds:
 
 ``cost_analysis()`` provides FLOPs / bytes-accessed; collective bytes are
 parsed from the compiled HLO text (``all-gather`` / ``all-reduce`` /
-``reduce-scatter`` / ``all-to-all`` / ``collective-permute``), taking the
-largest shape token on each collective line (the payload side: AG output,
-RS input, AR either).
+``reduce-scatter`` / ``all-to-all`` / ``collective-permute``) by
+``hlo_parse``: the payload side of each op (AG output, RS input, AR
+either; every element of a combined collective's tuple).
 
 Hardware model: TPU v5e — 197 TFLOP/s bf16/chip, 819 GB/s HBM, ~50 GB/s/link
 ICI.
 """
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional
+
+from repro.roofline.hlo_parse import _COLLECTIVES, HloModule
 
 HW = {
     "peak_flops": 197e12,   # bf16 / chip
@@ -27,39 +28,19 @@ HW = {
     "ici_bw": 50e9,         # bytes/s / link
 }
 
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
-
-_SHAPE_RE = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([0-9,]*)\]")
-
-
-def _shape_bytes(m) -> int:
-    dtype, dims = m.group(1), m.group(2)
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * _DTYPE_BYTES[dtype]
-
 
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
-    """Total payload bytes per collective kind in an HLO module."""
+    """Total payload bytes per collective kind in an HLO module: every
+    collective of every computation, counted once (no while-trip scaling),
+    with ``hlo_parse``'s payload convention
+    (:meth:`~repro.roofline.hlo_parse.HloModule.collective_payload`)."""
+    mod = HloModule(hlo_text)
     out = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        # op lines look like:  %name = TYPE all-gather(...), ...
-        for kind in _COLLECTIVES:
-            if f" {kind}(" in stripped or f" {kind}-start(" in stripped:
-                sizes = [_shape_bytes(m) for m in _SHAPE_RE.finditer(stripped)]
-                if sizes:
-                    out[kind] += max(sizes)
-                break
+    for ops in mod.computations.values():
+        for op in ops:
+            kind = op.opcode.replace("-start", "")
+            if kind in out:
+                out[kind] += mod.collective_payload(op)
     return out
 
 

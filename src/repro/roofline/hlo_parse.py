@@ -7,7 +7,7 @@ compiled per-device HLO text, computes per-computation
     * dot FLOPs              (2 · |result| · |contracted dims|)
     * bytes accessed         (operand reads + result writes of every
                               materializing top-level op — XLA convention)
-    * collective payloads    (per kind; max(result, operands) of the op)
+    * collective payloads    (per kind; ``HloModule.collective_payload``)
 
 and scales callee contributions through the call graph:
 ``while`` × known_trip_count (from backend_config, falling back to the
@@ -157,6 +157,17 @@ class HloModule:
         total += _shapes_bytes(re.sub(r"%[\w.\-]+", "", operand_text))
         return total
 
+    def collective_payload(self, op: Op) -> int:
+        """Payload of a collective: the larger of its result and its
+        operands (AG output, RS input, AR either). A combined collective's
+        tuple result counts every element. An async ``-start`` with a tuple
+        result pairs the operands with the output, so the operands are
+        taken out of it first."""
+        res, opd = op.result_bytes, self._operand_bytes(op)
+        if op.opcode.endswith("-start") and op.result_text.startswith("("):
+            res -= opd
+        return max(res, opd)
+
     def _dot_flops(self, op: Op) -> float:
         res = _shape_dims(op.result_text)
         if not res:
@@ -265,7 +276,7 @@ class HloModule:
             oc = op.opcode
             base = oc.replace("-start", "")
             if base in _COLLECTIVES:
-                payload = max(op.result_bytes, self._operand_bytes(op))
+                payload = self.collective_payload(op)
                 t.coll[base] = t.coll.get(base, 0.0) + payload
                 t.bytes += op.result_bytes + self._operand_bytes(op)
                 continue
@@ -376,7 +387,7 @@ def top_ops(text: str, kind: str = "collective", n: int = 12):
         for op in ops:
             base = op.opcode.replace("-start", "")
             if kind == "collective" and base in _COLLECTIVES:
-                cost = max(op.result_bytes, mod._operand_bytes(op))
+                cost = mod.collective_payload(op)
             elif kind == "flops" and op.opcode in ("dot", "dot_general"):
                 cost = mod._dot_flops(op)
             elif kind == "bytes" and op.opcode not in _FREE_OPS:

@@ -13,8 +13,10 @@ state.
 
 :class:`ResilientLoop` is the supervisor: it owns the step/retry state
 machine, classifies failures (OOM vs transient), applies exponential
-backoff, **resets the retry budget after every successful step** (one
-transient early plus another much later must not kill a long run), counts
+backoff, **resets the retry budget once a step past the failed one
+succeeds** (one transient early plus another much later must not kill a
+long run, while a failure that recurs every time its step is replayed from
+an earlier checkpoint still exhausts the budget), counts
 every fault into :class:`FaultCounters`, and always force-saves a final
 checkpoint on exit so a completed run is resumable/servable even when
 ``total_steps % interval != 0``.
@@ -209,6 +211,7 @@ class ResilientLoop:
         self.params = None
         self.opt_state = None
         self._consecutive_failures = 0
+        self._failed_step = -1
         self._last_saved: Optional[int] = None
         # snapshot of the iterator's initial position so a restore with no
         # checkpoint replays the exact token stream from the start
@@ -309,6 +312,7 @@ class ResilientLoop:
         else:
             self.counters.step_failures += 1
         self._consecutive_failures += 1
+        self._failed_step = max(self._failed_step, self.step)
         log.warning("step %d failed (%s); retry %d/%d from checkpoint",
                     self.step, e, self._consecutive_failures,
                     self.max_retries)
@@ -425,8 +429,9 @@ class ResilientLoop:
                 log.warning("step %d slow: %.2fs vs EWMA %.2fs",
                             self.step, dt, self.straggler.mean or 0.0)
             self.params, self.opt_state = new_params, new_opt
-            self._consecutive_failures = 0    # budget resets on success
             self.step += 1
+            if self.step > self._failed_step:
+                self._consecutive_failures = 0   # past the failure: reset
             res = StepResult(self.step, lossf, dt,
                              retried=self.counters.total_faults > 0)
             results.append(res)

@@ -55,8 +55,10 @@ log = logging.getLogger("repro.faults")
 KINDS = ("corrupt", "stall", "nan", "oom", "crash")
 
 #: substrings identifying a real allocator/runtime OOM in exception text
+#: (whole phrases only: a bare "OOM" also matches unrelated words and
+#: compiler diagnostics, which must never walk the degradation ladder)
 OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory",
-               "Allocation failure", "OOM")
+               "Allocation failure")
 
 
 class InjectedOOM(RuntimeError):

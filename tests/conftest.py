@@ -16,11 +16,5 @@ def fake_mesh():
     mesh *geometry* only, and a real Mesh can't be built from one CPU device
     (the emulated-fleet suite in tests/multihost/ covers real meshes)."""
     def make(data=4, model=4):
-        # JAX 0.4.x wants ((name, size), ...); 0.5+ wants (sizes, names).
-        try:
-            return jax.sharding.AbstractMesh((("data", data),
-                                              ("model", model)))
-        except TypeError:
-            return jax.sharding.AbstractMesh((data, model),
-                                             ("data", "model"))
+        return jax.sharding.AbstractMesh((data, model), ("data", "model"))
     return make

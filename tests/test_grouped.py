@@ -98,7 +98,15 @@ def test_ragged_matches_per_adapter_loop(sizes, quantized):
                                       has_aux=True)(x, a, b)
     np.testing.assert_allclose(np.asarray(yg), np.asarray(yl),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(float(lg), float(ll), rtol=1e-6, atol=1e-6)
+    # The loss is a float32 sum of ~2k tanh terms that cancels to |loss| < 1
+    # against a mass sum|tanh(y)| ~ 1e3, and the two programs reduce it in
+    # different orders: the sums can differ by one f32 rounding of the mass
+    # even when every y agrees to an ulp. The bound is the larger of 1e-6
+    # relative and that one rounding.
+    mass = float(jnp.sum(jnp.abs(jnp.tanh(yl))))
+    tol = max(1e-6 + 1e-6 * abs(float(ll)),
+              float(jnp.finfo(jnp.float32).eps) * mass)
+    assert abs(float(lg) - float(ll)) <= tol, (float(lg), float(ll), tol)
     assert _rel(gg, gl) <= 1e-5
     # dA rows of empty groups are exactly zero (no tiles launched for them)
     for g, sz in enumerate(sizes):

@@ -310,7 +310,7 @@ def test_quant_dispatch_falls_back_on_moe_shapes():
 
 
 def _sub_jaxprs(eqn):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     vals = []
     for v in eqn.params.values():
         vals += v if isinstance(v, (list, tuple)) else [v]

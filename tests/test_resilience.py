@@ -207,6 +207,27 @@ def test_consecutive_failures_still_raise(tmp_path):
         loop.run()
 
 
+def test_failure_recurring_on_replay_still_raises(tmp_path):
+    # step 2 fails every time it is reached and the only restore point is
+    # step 0: the replayed steps 0 and 1 succeed, which must not refill the
+    # retry budget (the run would otherwise retry forever)
+    it = make_batch_iterator(50, 4, 2, n_tokens=2048)
+    calls = {"n": 0}
+
+    def step_fn(params, opt_state, batch):
+        calls["n"] += 1
+        if float(params) == 2.0:
+            raise RuntimeError("deterministic failure at step 2")
+        return params + 1, opt_state, float(params)
+
+    loop = ResilientLoop(step_fn, lambda: (jnp.array(0.0), None), it,
+                         Checkpointer(str(tmp_path), interval=100), 8,
+                         max_retries=2, backoff_base=0.0)
+    with pytest.raises(RuntimeError, match="deterministic"):
+        loop.run()
+    assert calls["n"] == 3 * 3     # 1 try + 2 retries, 3 calls each
+
+
 def test_forced_final_checkpoint_on_exit(tmp_path):
     # total_steps % interval != 0: the loop must still leave a final
     # checkpoint at the last step
@@ -307,6 +328,61 @@ def test_oom_with_ladder_off_retries_in_place(tmp_path):
               degrade="off")).fit()
     assert res.degradations == []
     assert res.fault_counts["oom_events"] == 1
+    assert res.history[-1].step == 8
+
+
+_HBM_OOM = "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm"
+
+
+def _failing_compile(monkeypatch, batch, message):
+    """Make ``Trainer.compile_step`` raise ``message`` for specs of
+    ``batch`` (a compile error of that program), and compile the rest."""
+    real = Trainer.compile_step
+
+    def compile_step(self):
+        if self.live_spec.batch == batch:
+            raise RuntimeError(message)
+        return real(self)
+
+    monkeypatch.setattr(Trainer, "compile_step", compile_step)
+
+
+def test_compile_error_on_ladder_rung_raises(tmp_path, monkeypatch):
+    # a rung whose step does not compile (a Mosaic tiling error, say) is a
+    # fault of the program: the ladder raises it instead of skipping to a
+    # cheaper rung and reporting success there
+    _failing_compile(monkeypatch, 1, "Mosaic failed to compile TPU kernel")
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        Trainer.from_spec(
+            _spec(tmp_path, "rungerr", inject_faults="oom@3")).fit()
+
+
+def test_rung_oom_at_compile_is_skipped(tmp_path, monkeypatch):
+    # halve_batch does not fit either (compile-time OOM): the ladder moves
+    # on to the next rung
+    _failing_compile(monkeypatch, 1, _HBM_OOM)
+    res = Trainer.from_spec(
+        _spec(tmp_path, "rungoom", inject_faults="oom@3")).fit()
+    assert res.degradations == ["engine_mesp_seq"]
+    assert (res.final_spec.batch, res.final_spec.engine) == (2, "mesp_seq")
+    assert res.history[-1].step == 8
+
+
+@pytest.mark.parametrize("degrade", ["on", "off"])
+def test_compile_oom_at_start(tmp_path, monkeypatch, degrade):
+    # the step of the requested spec does not fit at compile: with the
+    # ladder on, the first step's OOM (injected here, as the same program
+    # would raise it) walks the ladder; with it off, fit raises outright
+    _failing_compile(monkeypatch, 2, _HBM_OOM)
+    spec = _spec(tmp_path, f"startoom_{degrade}", inject_faults="oom@0",
+                 degrade=degrade)
+    if degrade == "off":
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            Trainer.from_spec(spec).fit()
+        return
+    res = Trainer.from_spec(spec).fit()
+    assert res.degradations == ["halve_batch"]
+    assert res.final_spec.batch == 1
     assert res.history[-1].step == 8
 
 

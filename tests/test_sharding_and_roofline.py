@@ -91,12 +91,44 @@ def test_hlo_analyzer_nested_while():
 
 def test_collective_regex():
     text = """
+ENTRY %main () -> () {
   %ag = bf16[16,1024]{1,0} all-gather(bf16[16,64]{1,0} %x), replica_groups={}
   %ar.1 = f32[256,256]{1,0} all-reduce(f32[256,256]{1,0} %y), to_apply=%sum
+}
 """
     out = collective_bytes(text)
     assert out["all-gather"] == 16 * 1024 * 2
     assert out["all-reduce"] == 256 * 256 * 4
+
+
+def test_collective_parse_combined_and_async():
+    """XLA combines the per-layer gradient all-reduces into one op with a
+    tuple result: every element is payload. Async starts of all-gather
+    pair the operand with the output: only the output counts. A
+    reduce-scatter counts its input, as ``hlo_parse`` counts it."""
+    text = """
+ENTRY %main () -> () {
+  %a = f32[64,4]{1,0} parameter(0)
+  %b = f32[4,32]{1,0} parameter(1)
+  %c = f32[128,4]{1,0} parameter(2)
+  %d = s32[] parameter(3)
+  %e = s32[] parameter(4)
+  %x = bf16[16,64]{1,0} parameter(5)
+  %z = f32[32]{0} parameter(6)
+  %ar = (f32[64,4]{1,0}, f32[4,32]{1,0}, /*index=2*/f32[128,4]{1,0}) all-reduce(%a, %b, %c), to_apply=%sum
+  %s = (s32[], s32[]) all-reduce(%d, %e), to_apply=%sum
+  %ags = (bf16[16,64]{1,0}, bf16[16,1024]{1,0}) all-gather-start(%x), dimensions={1}
+  %agd = bf16[16,1024]{1,0} all-gather-done(%ags)
+  %ars = f32[32]{0} all-reduce-start(%z), to_apply=%sum
+  %rs = f32[8]{0} reduce-scatter(%z), dimensions={0}, to_apply=%sum
+}
+"""
+    out = collective_bytes(text)
+    assert out["all-reduce"] == (64 * 4 + 4 * 32 + 128 * 4) * 4 + 8 + 32 * 4
+    assert out["all-gather"] == 16 * 1024 * 2
+    assert out["reduce-scatter"] == 32 * 4
+    assert analyze_text(text).coll == {k: float(v) for k, v in out.items()
+                                       if v}
 
 
 def test_model_flops_accounting():

@@ -68,6 +68,22 @@ def test_optimizers_converge_and_respect_none(opt):
     assert float(p["frozen"][0]) == 7.0  # None grad => untouched
 
 
+@pytest.mark.parametrize("opt", [sgd(constant(0.1)), sgd_momentum(0.05),
+                                 adamw(0.1)])
+def test_optimizers_keep_param_dtype(opt):
+    # a bf16 param updated with an f32 learning-rate array and f32 grads
+    # must stay bf16: a dtype change would retrace the jitted train step
+    p = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16),
+                               _quadratic_params())
+    state = opt.init(p)
+    for _ in range(2):
+        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
+                                       _quadratic_grads(p))
+        p, state = opt.update(grads, state, p)
+    assert {x.dtype for x in jax.tree_util.tree_leaves(p)} == {
+        jnp.dtype(jnp.bfloat16)}
+
+
 def test_schedules():
     s = warmup_cosine(1.0, 10, 100)
     assert float(s(jnp.array(5))) == pytest.approx(0.5)
