@@ -5,7 +5,8 @@ Three modes:
 * **report** (default) — load every ``*.jsonl`` under a run directory
   (``--run DIR``), print the event-kind counts, step-loss trajectory,
   checkpoint/fault/degrade timeline, and span totals from ``trace.json``
-  when present.
+  when present, in the loops' order (``telemetry.spans.LOOP_SPANS``, then
+  ``SERVE_SPANS``) with each span's mean in ms.
 
 * **validate** (``--validate``) — schema-check every record
   (``repro.telemetry.events.validate_record``): envelope version, required
@@ -42,6 +43,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.telemetry import events as ev  # noqa: E402
+from repro.telemetry.spans import LOOP_SPANS, SERVE_SPANS  # noqa: E402
 
 RESULTS_DIR = (Path(__file__).resolve().parent.parent / "benchmarks" /
                "results")
@@ -116,9 +118,13 @@ def summarize(records: list[dict], run_dir: str) -> dict:
             t = totals.setdefault(s["name"], {"count": 0, "total_s": 0.0})
             t["count"] += 1
             t["total_s"] += s["dur"] / 1e6
-        out["spans"] = {k: {"count": v["count"],
-                            "total_s": round(v["total_s"], 4)}
-                        for k, v in sorted(totals.items())}
+        order = {n: i for i, n in enumerate(LOOP_SPANS + SERVE_SPANS)}
+        out["spans"] = {
+            k: {"count": v["count"], "total_s": round(v["total_s"], 4),
+                "mean_ms": round(1e3 * v["total_s"] / v["count"], 4)}
+            for k, v in sorted(totals.items(),
+                               key=lambda kv: (order.get(kv[0], len(order)),
+                                               kv[0]))}
     return out
 
 

@@ -72,12 +72,16 @@ class Trainer:
 
     ``cfg`` overrides the spec's ``arch``/``reduced`` resolution with an
     explicit ArchConfig (used by examples that build custom configs).
+    ``counters`` (``train.steps``, ``train.host_syncs``) count over every
+    ``fit`` of this trainer: steps completed and the loop's device→host
+    reads (the loss, and each float leaf's norm while the guard runs).
     """
 
     def __init__(self, spec: TrainSpec, *, cfg=None, mesh=None):
         from repro.configs import get_config
         from repro.optim import make_optimizer
         from repro.optim.schedules import constant
+        from repro.telemetry.metrics import CounterGroup
 
         self.spec = spec.validate()
         if cfg is None:
@@ -87,6 +91,7 @@ class Trainer:
         self.cfg = cfg
         self.opt = make_optimizer(spec.optimizer, constant(spec.lr))
         self.mesh = mesh if mesh is not None else self._auto_mesh(self.spec)
+        self.counters = CounterGroup("train", ("steps", "host_syncs"))
         self._live_spec: Optional[TrainSpec] = None
         self._switch_to(self.spec)
 
@@ -471,7 +476,7 @@ class Trainer:
             straggler=straggler, guard=guard, injector=injector,
             on_step=_log_step, on_oom=on_oom, restore_fn=restore_fn,
             extra_fn=extra_fn, telemetry=tel, memwatch=memwatch,
-            pressure=pressure)
+            pressure=pressure, train_counters=self.counters)
         if tel.enabled:
             tel.emit(tele.RunEvent(
                 phase="start", engine=spec0.engine, quantize=spec0.quantize,

@@ -9,7 +9,8 @@ ExecutionPolicy backend.
   structured jnp path (``core/structured``) on unsupported shapes — per-op,
   so one unsupported op never drags the whole block off the kernel path
   (MoE per-expert [E,·,·] linears have their own grouped kernel family
-  below and no longer fall back);
+  below and no longer fall back). Each fallback is counted in
+  :data:`FALLBACKS` when the op is traced;
 * picks block sizes from ``kernels/autotune.py`` (heuristic table, optionally
   overridden by a measured cache);
 * runs the Pallas kernel with ``interpret=True`` automatically on non-TPU
@@ -40,6 +41,13 @@ from repro.kernels import rmsnorm as _rn
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rope as _rope
 from repro.kernels import tiling
+from repro.telemetry.metrics import CounterGroup
+
+#: per-op jnp fallbacks of the dispatchers below ("kernels.fallback.*"),
+#: counted at trace time: once per call site each time a program is traced,
+#: not per execution. Module-level for the reason ``autotune.COUNTERS`` is;
+#: an enabled Telemetry adopts the group.
+FALLBACKS = CounterGroup("kernels.fallback", ("lora_linear", "sdpa"))
 
 # Below this many query rows the dense structured sdpa beats the kernel's
 # padding + grid overhead (and is easier to cross-check).
@@ -219,6 +227,7 @@ def lora_linear(x, w0, a, b, bias=None, scale: float = 2.0, *,
     (``core/quant.maybe_dequant``). ``policy`` (ExecutionPolicy) supplies
     kernel overrides (interpret)."""
     if not lora_supported(x, w0):
+        FALLBACKS["lora_linear"] += 1
         return structured.lora_linear(x, quant.maybe_dequant(w0, x.dtype),
                                       a, b, bias, scale)
     interpret = _resolve_interpret(policy, interpret)
@@ -553,6 +562,7 @@ def sdpa(q, k, v, *, causal: bool = True, window: int = 0, policy=None,
     (layers skip the jnp rotation when fusing): the kernel path rotates q/k
     tiles in VMEM; the fallback applies the same tables via jnp first."""
     if not attention_supported(q, k):
+        FALLBACKS["sdpa"] += 1
         if rope is not None:
             q = _rope.apply_rope_tables(q, *rope)
             k = _rope.apply_rope_tables(k, *rope)

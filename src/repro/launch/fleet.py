@@ -187,7 +187,7 @@ def task_train(payload: dict) -> dict:
             batch = synth_batch(tr.cfg.vocab, spec.batch, spec.seq,
                                 spec.seed, step)
             t0 = time.perf_counter()
-            with tel.span("step"):
+            with tel.span("fleet/step"):
                 params, opt_state, loss = jax.block_until_ready(
                     tr.step_fn(params, opt_state, batch))
             dt = time.perf_counter() - t0

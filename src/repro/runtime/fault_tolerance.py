@@ -33,8 +33,12 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+from jax.profiler import StepTraceAnnotation
+
 from repro.checkpoint import Checkpointer
 from repro.runtime.faults import is_oom_error
+from repro.runtime.guard import update_norm
+from repro.telemetry.metrics import CounterGroup
 
 log = logging.getLogger("repro.ft")
 
@@ -148,11 +152,16 @@ class ResilientLoop:
     * ``extra_fn()`` — dict merged into every checkpoint manifest (the
       Trainer records the live spec so restores are self-describing).
     * ``telemetry`` — :class:`repro.telemetry.Telemetry`; when enabled the
-      loop emits typed step/fault/checkpoint/watermark events, wraps
-      data-fetch/step/checkpoint/restore in trace spans and keeps
-      ``train.*`` metrics. Disabled (the default) the hot path pays one
-      flag check and nothing else — same jitted step object, no span or
-      record allocation (asserted by tests/test_telemetry.py).
+      loop emits typed step/fault/checkpoint/watermark events, records its
+      spans into ``trace.json`` and keeps ``train.*`` metrics. Enabled or
+      not, each step attempt is a ``StepTraceAnnotation("train")`` and its
+      phases are profiler annotations (``telemetry.spans.LOOP_SPANS``);
+      disabled, that is all the hot path pays — same jitted step object,
+      no record built (asserted by tests/test_telemetry.py).
+    * ``train_counters`` — :class:`~repro.telemetry.metrics.CounterGroup`
+      ``train`` (``steps`` completed, ``host_syncs``: device→host reads of
+      the loss and of the guard's per-leaf norms); registered with an
+      enabled telemetry.
     * ``memwatch`` — :class:`repro.telemetry.MemoryWatermark`; sampled after
       every successful step.
     * ``pressure`` — :class:`repro.runtime.degrade.WatermarkTrigger`; fed
@@ -179,7 +188,8 @@ class ResilientLoop:
                  extra_fn: Optional[Callable[[], dict]] = None,
                  telemetry=None,
                  memwatch=None,
-                 pressure=None):
+                 pressure=None,
+                 train_counters: Optional[CounterGroup] = None):
         self.step_fn = step_fn
         self.init_state = init_state
         self.batch_iter = batch_iter
@@ -200,6 +210,13 @@ class ResilientLoop:
             from repro.telemetry import DISABLED
             telemetry = DISABLED
         self.telemetry = telemetry
+        self.train_counters = (train_counters if train_counters is not None
+                               else CounterGroup("train",
+                                                 ("steps", "host_syncs")))
+        if telemetry.enabled:
+            telemetry.registry.register_group(self.train_counters)
+        self._steps = self.train_counters.counter("steps")
+        self._syncs = self.train_counters.counter("host_syncs")
         self.memwatch = memwatch
         self.pressure = pressure
         #: why the current on_oom invocation happened ("oom" | "watermark");
@@ -226,7 +243,7 @@ class ResilientLoop:
         return state.to_dict() if state is not None else None
 
     def _restore(self):
-        with self.telemetry.span("restore"):
+        with self.telemetry.span("train/restore"):
             return self._restore_inner()
 
     def _restore_inner(self):
@@ -271,7 +288,7 @@ class ResilientLoop:
     # ----------------------------------------------------------------- save
     def _save_now(self) -> None:
         t0 = time.monotonic()
-        with self.telemetry.span("checkpoint"):
+        with self.telemetry.span("train/checkpoint"):
             self.ckpt.save(self.step, self.params, self.opt_state,
                            data_state=self._data_state_dict(),
                            extra=self.extra_fn() if self.extra_fn else None)
@@ -373,89 +390,11 @@ class ResilientLoop:
 
     # ------------------------------------------------------------------ run
     def run(self):
-        from repro.runtime.guard import update_norm as _update_norm
-
         self.step, self.params, self.opt_state = self._restore()
-        tel = self.telemetry
         results = []
         while self.step < self.total_steps:
-            t0 = time.monotonic()
-            try:
-                if self.injector is not None:
-                    self.injector.before_step(self.step)
-                # one flag check on the hot path: the disabled branch runs
-                # the exact pre-telemetry code, no span/context allocation
-                if tel.enabled:
-                    with tel.span("data_fetch"):
-                        batch = next(self.batch_iter)
-                    with tel.span("step"):
-                        new_params, new_opt, loss = self.step_fn(
-                            self.params, self.opt_state, batch)
-                else:
-                    batch = next(self.batch_iter)
-                    new_params, new_opt, loss = self.step_fn(
-                        self.params, self.opt_state, batch)
-                if self.injector is not None:
-                    loss = self.injector.after_step(self.step, loss)
-                lossf = float(loss)
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception as e:
-                self._handle_failure(e)
-                continue
-            if self.guard is not None:
-                unorm = (_update_norm(self.params, new_params)
-                         if self.guard.track_update_norm else None)
-                if self.guard.observe(lossf, update_norm=unorm,
-                                      step=self.step) == "reject":
-                    self.counters.guard_skips += 1
-                    continue      # rewind: update discarded, batch skipped
-            dt = time.monotonic() - t0
-            verdict = self.straggler.observe(dt)
-            if verdict == "restart":
-                self.counters.straggler_restarts += 1
-                if self.counters.straggler_restarts > self.restart_budget:
-                    raise RestartRequired(
-                        f"step {self.step}: {dt:.1f}s >= "
-                        f"{self.straggler.factor}x EWMA for "
-                        f"{self.straggler.limit} consecutive steps")
-                log.warning("straggler watchdog: supervised restart %d/%d "
-                            "at step %d (%.1fs step)",
-                            self.counters.straggler_restarts,
-                            self.restart_budget, self.step, dt)
-                self.step, self.params, self.opt_state = self._restore()
-                continue
-            elif verdict == "slow":
-                log.warning("step %d slow: %.2fs vs EWMA %.2fs",
-                            self.step, dt, self.straggler.mean or 0.0)
-            self.params, self.opt_state = new_params, new_opt
-            self.step += 1
-            if self.step > self._failed_step:
-                self._consecutive_failures = 0   # past the failure: reset
-            res = StepResult(self.step, lossf, dt,
-                             retried=self.counters.total_faults > 0)
-            results.append(res)
-            if tel.enabled:
-                from repro.telemetry import StepEvent
-                tel.emit(StepEvent(step=self.step, loss=lossf, seconds=dt))
-                tel.registry.counter("train.steps").inc()
-                tel.registry.gauge("train.loss").set(lossf)
-                tel.registry.histogram("train.step_seconds").record(dt)
-            if self.memwatch is not None:
-                self._sample_watermark()
-            if self.on_step:
-                self.on_step(res)
-            saved = self.ckpt.maybe_save(
-                self.step, self.params, self.opt_state,
-                data_state=self._data_state_dict(),
-                extra=self.extra_fn() if self.extra_fn else None)
-            if saved:
-                self._last_saved = self.step
-                if tel.enabled:
-                    from repro.telemetry import CheckpointEvent
-                    tel.emit(CheckpointEvent(action="save", step=self.step,
-                                             path=self.ckpt.directory))
-                    tel.registry.counter("ckpt.saves").inc()
+            with StepTraceAnnotation("train", step_num=self.step):
+                self._attempt(results)
         # forced final save: a completed run is always resumable/servable
         # from its last step, even when total_steps % interval != 0
         if self.step > 0 and self._last_saved != self.step:
@@ -465,6 +404,89 @@ class ResilientLoop:
         self.counters.ckpt_quarantines = len(
             getattr(self.ckpt, "quarantined", ()))
         return self.params, self.opt_state, results, self.counters
+
+    def _attempt(self, results: list) -> None:
+        """One attempt at step ``self.step``: commits it (appending its
+        :class:`StepResult`), or rewinds, retries or restores."""
+        tel = self.telemetry
+        t0 = time.monotonic()
+        try:
+            if self.injector is not None:
+                self.injector.before_step(self.step)
+            with tel.span("train/data"):
+                batch = next(self.batch_iter)
+            with tel.span("train/dispatch"):
+                new_params, new_opt, loss = self.step_fn(
+                    self.params, self.opt_state, batch)
+            if self.injector is not None:
+                loss = self.injector.after_step(self.step, loss)
+            with tel.span("train/loss_sync"):
+                lossf = float(loss)
+            self._syncs.inc()
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except Exception as e:
+            self._handle_failure(e)
+            return
+        if self.guard is not None:
+            with tel.span("train/guard"):
+                unorm = (update_norm(self.params, new_params,
+                                     syncs=self._syncs)
+                         if self.guard.track_update_norm else None)
+                verdict = self.guard.observe(lossf, update_norm=unorm,
+                                             step=self.step)
+            if verdict == "reject":
+                self.counters.guard_skips += 1
+                return        # rewind: update discarded, batch skipped
+        dt = time.monotonic() - t0
+        verdict = self.straggler.observe(dt)
+        if verdict == "restart":
+            self.counters.straggler_restarts += 1
+            if self.counters.straggler_restarts > self.restart_budget:
+                raise RestartRequired(
+                    f"step {self.step}: {dt:.1f}s >= "
+                    f"{self.straggler.factor}x EWMA for "
+                    f"{self.straggler.limit} consecutive steps")
+            log.warning("straggler watchdog: supervised restart %d/%d "
+                        "at step %d (%.1fs step)",
+                        self.counters.straggler_restarts,
+                        self.restart_budget, self.step, dt)
+            self.step, self.params, self.opt_state = self._restore()
+            return
+        elif verdict == "slow":
+            log.warning("step %d slow: %.2fs vs EWMA %.2fs",
+                        self.step, dt, self.straggler.mean or 0.0)
+        self.params, self.opt_state = new_params, new_opt
+        self.step += 1
+        self._steps.inc()
+        if self.step > self._failed_step:
+            self._consecutive_failures = 0   # past the failure: reset
+        res = StepResult(self.step, lossf, dt,
+                         retried=self.counters.total_faults > 0)
+        results.append(res)
+        if tel.enabled:
+            from repro.telemetry import StepEvent
+            tel.emit(StepEvent(step=self.step, loss=lossf, seconds=dt))
+            tel.registry.gauge("train.loss").set(lossf)
+            tel.registry.histogram("train.step_seconds").record(dt)
+        if self.memwatch is not None:
+            with tel.span("train/watermark"):
+                self._sample_watermark()
+        if self.on_step:
+            with tel.span("train/on_step"):
+                self.on_step(res)
+        with tel.span("train/checkpoint"):
+            saved = self.ckpt.maybe_save(
+                self.step, self.params, self.opt_state,
+                data_state=self._data_state_dict(),
+                extra=self.extra_fn() if self.extra_fn else None)
+        if saved:
+            self._last_saved = self.step
+            if tel.enabled:
+                from repro.telemetry import CheckpointEvent
+                tel.emit(CheckpointEvent(action="save", step=self.step,
+                                         path=self.ckpt.directory))
+                tel.registry.counter("ckpt.saves").inc()
 
 
 def run_resilient(step_fn: Callable[[Any, Any, dict], tuple],

@@ -38,9 +38,11 @@ class GuardExhausted(RuntimeError):
     """Raised when a run rejects more steps than its guard budget allows."""
 
 
-def update_norm(old_params, new_params) -> float:
+def update_norm(old_params, new_params, syncs=None) -> float:
     """Global L2 norm of the parameter update over float leaves (LoRA
-    factors; frozen int8 leaves are unchanged and skipped)."""
+    factors; frozen int8 leaves are unchanged and skipped). Each float
+    leaf's sum is read back to the host on its own; ``syncs`` (a
+    :class:`~repro.telemetry.metrics.Counter`) counts those reads."""
     total = 0.0
     for a, b in zip(jax.tree_util.tree_leaves(old_params),
                     jax.tree_util.tree_leaves(new_params)):
@@ -48,6 +50,8 @@ def update_norm(old_params, new_params) -> float:
             continue
         d = (jnp.asarray(b, jnp.float32) - jnp.asarray(a, jnp.float32))
         total += float(jnp.sum(d * d))
+        if syncs is not None:
+            syncs.inc()
     return math.sqrt(total)
 
 

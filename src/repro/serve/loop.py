@@ -124,7 +124,7 @@ class ContinuousBatcher:
                       "rejected_store"))
         # one namespaced registry over the three formerly-private counter
         # dicts (serve.* / store.* / pages.*); a telemetry object shares its
-        # registry (and gains spans + admission events), otherwise the
+        # registry (and records spans + admission events), otherwise the
         # batcher owns a local one — snapshot via .metrics()
         self._tel = telemetry if telemetry is not None else _NO_TELEMETRY
         self.registry = (telemetry.registry if telemetry is not None
@@ -219,7 +219,8 @@ class ContinuousBatcher:
             self.tile_adapter[t] = req.adapter
         self.tile_gid[t] = slot
         b = next(i for i in self._tile_rows(t) if self._rows[i].req is None)
-        self.cache = _reset_slot(self.cache, b)
+        with self._tel.span("serve/reset_slot"):
+            self.cache = _reset_slot(self.cache, b)
         self._rows[b] = _Slot(req=req, pending=list(req.prompt))
         self.counters["admitted"] += 1
         if self._tel.enabled:
@@ -260,30 +261,21 @@ class ContinuousBatcher:
         """Admit, then advance every active row by one token. Returns False
         when there is nothing to do (no active rows, empty queue)."""
         tel = self._tel
-        if tel.enabled:
-            with tel.span("admission"):
-                self._admit()
-        else:
+        with tel.span("serve/admission"):
             self._admit()
         if self.active == 0:
             return False
         toks = np.zeros((self.slots, 1), np.int32)
-        prefilling = any(r.req is not None and r.pending for r in self._rows)
         for b, row in enumerate(self._rows):
             if row.req is not None:
                 toks[b, 0] = row.pending[0] if row.pending else row.last
-        if tel.enabled:
-            # prefill runs through the same step (prefill-as-decode); the
-            # span name records which phase this step predominantly served
-            with tel.span("prefill" if prefilling else "decode"):
-                logits, self.cache = self._jstep(
-                    self.store.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.tile_gid))
-        else:
+        # prompt and generation rows share one step (prefill-as-decode)
+        with tel.span("serve/dispatch"):
             logits, self.cache = self._jstep(
                 self.store.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(self.tile_gid))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
+        with tel.span("serve/argmax_sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
         self.counters["steps"] += 1
         done = []
         for b, row in enumerate(self._rows):

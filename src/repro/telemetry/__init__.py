@@ -2,15 +2,17 @@
 
 :class:`Telemetry` is the one object threaded through the trainer, resilient
 loop, guard, and serve loop.  Disabled (the default) it is a frozen shell:
-``enabled`` is False, ``span()`` returns the shared no-op singleton, and
-``emit()`` returns immediately — the hot step path pays one attribute check
-and nothing else (asserted by tests/test_telemetry.py).  Enabled, it owns
+``enabled`` is False, ``span()`` returns a bare profiler annotation that
+records nothing, and ``emit()`` returns immediately — the hot step path
+pays one annotation per span and nothing else (asserted by
+tests/test_telemetry.py).  Enabled, it owns
 
 * a :class:`~repro.telemetry.metrics.MetricRegistry` (counters / gauges /
   histograms, unified across train/serve/autotune),
 * event sinks (in-memory always; JSONL under ``--telemetry-dir``),
 * a :class:`~repro.telemetry.spans.Tracer` with Chrome-trace export, and
-* optional ``jax.profiler`` capture (``--profile on``).
+* optional ``jax.profiler`` capture (``--profile on``), whose session
+  start is the tracer's epoch.
 
 The module also hosts the structured *console* logging choke point
 (:func:`log_step`, :func:`log_run_summary`) that replaced the ad-hoc
@@ -21,6 +23,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 from typing import List, Optional
 
 from repro.telemetry import events as ev
@@ -32,11 +35,12 @@ from repro.telemetry.events import (AdmissionEvent, CheckpointEvent,
 from repro.telemetry.memwatch import MemoryWatermark
 from repro.telemetry.metrics import (Counter, CounterGroup, Gauge, Histogram,
                                      MetricRegistry)
-from repro.telemetry.spans import NULL_SPAN, Tracer
+from repro.telemetry.spans import LOOP_SPANS, SERVE_SPANS, Tracer
 
 __all__ = [
     "Telemetry", "DISABLED", "MemoryWatermark", "MetricRegistry",
-    "CounterGroup", "Counter", "Gauge", "Histogram", "Tracer", "NULL_SPAN",
+    "CounterGroup", "Counter", "Gauge", "Histogram", "Tracer", "LOOP_SPANS",
+    "SERVE_SPANS",
     "SCHEMA_VERSION", "RunEvent", "StepEvent", "FaultEvent", "DegradeEvent",
     "GuardEvent", "AdmissionEvent", "CheckpointEvent", "WatermarkEvent",
     "log_step", "log_run_summary",
@@ -71,13 +75,22 @@ class Telemetry:
                     else f"worker_{worker}.jsonl")
             self.sinks.append(ev.JsonlSink(os.path.join(out_dir, name)))
         if profile and out_dir:
+            # the profile's times count from its session's start, which
+            # falls inside start_trace: the call's midpoint is within half
+            # the call of it, and becomes the tracer's epoch, so trace.json
+            # shares the profile's origin
+            before = time.perf_counter()
             self._profiling = sp.start_profiler(
                 os.path.join(out_dir, "profile"))
-        # autotune counters are module-global (kernels cannot depend on a
+            if self._profiling:
+                self.tracer.epoch = (before + time.perf_counter()) / 2
+        # kernel counters are module-global (kernels cannot depend on a
         # run-scoped object); adopt them so snapshots include cache traffic
+        # and per-op fallbacks
         try:
-            from repro.kernels import autotune
+            from repro.kernels import autotune, ops
             self.registry.register_group(autotune.COUNTERS)
+            self.registry.register_group(ops.FALLBACKS)
         except Exception:  # pragma: no cover - kernels optional in tests
             pass
 
@@ -102,8 +115,10 @@ class Telemetry:
             s.emit(rec)
 
     def span(self, name: str):
+        """A profiler annotation named ``name``; recorded for
+        ``trace.json`` too when enabled."""
         if not self.enabled:
-            return NULL_SPAN
+            return sp.TraceAnnotation(name)
         return self.tracer.span(name)
 
     # ------------------------------------------------------------- queries

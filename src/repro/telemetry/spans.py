@@ -1,13 +1,21 @@
-"""Nested wall-clock trace spans with Chrome-trace/Perfetto export.
+"""Trace spans on the profiler's clock, with Chrome-trace/Perfetto export.
 
-A :class:`Tracer` records complete ("ph": "X") spans; nesting comes from the
-enter/exit timing, which Perfetto and chrome://tracing reconstruct into a
-flame view.  Disabled tracing costs nothing: :data:`NULL_SPAN` is one shared
-``contextlib``-style no-op context manager, so ``tracer.span(...)`` on a
-disabled tracer allocates no objects (asserted by tests).
+Every span enters ``jax.profiler.TraceAnnotation(name)``: while a profiler
+session runs, the span is a host event in the same trace as the device's
+operations, so a gap on the device can be attributed to the host work
+that covers it. With no session running an annotation costs under a
+microsecond.
 
-``jax.profiler`` start/stop hooks live here too (behind ``--profile``); they
-are best-effort and never fail the run.
+A :class:`Tracer` that is enabled also records each span as a complete
+("ph": "X") event, ``(name, start_s, dur_s, depth)`` relative to its
+epoch, for ``trace.json``. A disabled tracer records nothing: its
+``span()`` is the bare annotation. When :class:`~repro.telemetry.Telemetry`
+starts the profiler itself (``--profile on``) it moves the epoch to the
+session's start (the midpoint of the call that starts it), so
+``trace.json`` and the profile share an origin.
+
+:data:`LOOP_SPANS` and :data:`SERVE_SPANS` name every span the training
+and serving loops open; readers of a trace take the names from there.
 """
 from __future__ import annotations
 
@@ -15,36 +23,37 @@ import json
 import os
 import threading
 import time
-from typing import List, Optional
+from typing import List
 
+from jax.profiler import TraceAnnotation
 
-class _NullSpan:
-    """Shared no-op context manager (singleton: :data:`NULL_SPAN`)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
+#: the training loop's spans (``runtime/fault_tolerance.ResilientLoop``),
+#: in loop order; each step attempt sits inside a
+#: ``StepTraceAnnotation("train", step_num=step)``
+LOOP_SPANS = ("train/restore", "train/data", "train/dispatch",
+              "train/loss_sync", "train/guard", "train/watermark",
+              "train/on_step", "train/checkpoint")
+#: the serve loop's spans (``serve/loop.ContinuousBatcher.step``);
+#: ``serve/reset_slot`` nests inside ``serve/admission``
+SERVE_SPANS = ("serve/admission", "serve/reset_slot", "serve/dispatch",
+               "serve/argmax_sync")
 
 
 class Span:
-    """One live span; records itself on the tracer at ``__exit__``."""
+    """One live span: a profiler annotation, recorded on the tracer at
+    ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "t0", "depth")
+    __slots__ = ("tracer", "name", "t0", "depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str):
         self.tracer = tracer
         self.name = name
         self.t0 = 0.0
         self.depth = 0
+        self._ann = TraceAnnotation(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         tr = self.tracer
         self.depth = len(tr._stack)
         tr._stack.append(self)
@@ -57,12 +66,13 @@ class Span:
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
         tr.finished.append((self.name, self.t0 - tr.epoch, dur, self.depth))
+        self._ann.__exit__(*exc)
         return False
 
 
 class Tracer:
     """Collects finished spans as ``(name, start_s, dur_s, depth)`` tuples
-    relative to the tracer's epoch."""
+    relative to the tracer's epoch (``time.perf_counter`` seconds)."""
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
@@ -72,7 +82,7 @@ class Tracer:
 
     def span(self, name: str):
         if not self.enabled:
-            return NULL_SPAN
+            return TraceAnnotation(name)
         return Span(self, name)
 
     # ------------------------------------------------------------ export
@@ -100,9 +110,6 @@ class Tracer:
             row["count"] += 1
             row["total_s"] += dur
         return agg
-
-
-NULL_TRACER = Tracer(enabled=False)
 
 
 # ----------------------------------------------------------- jax.profiler

@@ -1,4 +1,4 @@
-"""Telemetry subsystem tests: the zero-cost disabled path, the JSONL event
+"""Telemetry subsystem tests: the inert disabled path, the JSONL event
 schema, metric registry namespacing, the memory watermark vs the memsim
 prediction, fleet shard-merge determinism, and the typed-event timeline of
 a chaos run through ``Trainer.fit``."""
@@ -7,11 +7,12 @@ import json
 import os
 import random
 
+import jax
 import pytest
 
 from repro.api import Trainer, TrainSpec
 from repro.telemetry import (DISABLED, CounterGroup, MemoryWatermark,
-                             MetricRegistry, NULL_SPAN, SCHEMA_VERSION,
+                             MetricRegistry, SCHEMA_VERSION,
                              StepEvent, Telemetry)
 from repro.telemetry import events as ev
 from repro.telemetry import spans as sp
@@ -27,12 +28,18 @@ def _tiny_spec(tmp_path, **kw):
     return TrainSpec(**base)
 
 
-# ----------------------------------------------------- disabled = zero cost
+# ------------------------------------------ disabled = one annotation a span
 def test_disabled_singleton_is_inert():
     assert DISABLED.enabled is False
     assert DISABLED.sinks == []
-    # the same shared no-op span object every call — no allocation
-    assert DISABLED.span("a") is DISABLED.span("b") is NULL_SPAN
+    # a disabled span is the bare profiler annotation: the tracer records
+    # nothing and no event is emitted
+    span = DISABLED.span("a")
+    assert type(span) is jax.profiler.TraceAnnotation
+    with span, DISABLED.span("b"):
+        pass
+    assert DISABLED.tracer.finished == []
+    assert DISABLED.tracer._stack == []
     DISABLED.emit(StepEvent(step=1, loss=0.5, seconds=0.1))   # no-op
     assert DISABLED.events() == []
     assert DISABLED.counts_by_kind() == {}
@@ -225,7 +232,7 @@ def test_fit_telemetry_watermark_vs_memsim(tmp_path):
     assert m["events_by_kind"]["run"] == 2      # start + end
     assert m["events_by_kind"]["watermark"] == 3
     assert m["registry"]["train.steps"] == 3
-    assert m["spans"]["step"]["count"] == 3
+    assert m["spans"]["train/dispatch"]["count"] == 3
     # files on disk: schema-valid JSONL + a Chrome trace
     recs = ev.read_jsonl(os.path.join(tdir, "events.jsonl"))
     assert all(ev.validate_record(r) == [] for r in recs)
