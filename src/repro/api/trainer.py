@@ -74,7 +74,8 @@ class Trainer:
     explicit ArchConfig (used by examples that build custom configs).
     ``counters`` (``train.steps``, ``train.host_syncs``) count over every
     ``fit`` of this trainer: steps completed and the loop's device→host
-    reads (the loss, and each float leaf's norm while the guard runs).
+    reads (one a step: the loss, with the guard's update norm while the
+    guard runs).
     """
 
     def __init__(self, spec: TrainSpec, *, cfg=None, mesh=None):

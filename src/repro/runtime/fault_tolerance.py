@@ -29,15 +29,17 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 from jax.profiler import StepTraceAnnotation
 
 from repro.checkpoint import Checkpointer
 from repro.runtime.faults import is_oom_error
-from repro.runtime.guard import update_norm
+from repro.runtime.guard import update_sq_norm
 from repro.telemetry.metrics import CounterGroup
 
 log = logging.getLogger("repro.ft")
@@ -159,9 +161,9 @@ class ResilientLoop:
       disabled, that is all the hot path pays — same jitted step object,
       no record built (asserted by tests/test_telemetry.py).
     * ``train_counters`` — :class:`~repro.telemetry.metrics.CounterGroup`
-      ``train`` (``steps`` completed, ``host_syncs``: device→host reads of
-      the loss and of the guard's per-leaf norms); registered with an
-      enabled telemetry.
+      ``train`` (``steps`` completed, ``host_syncs``: device→host reads, one
+      a step — the loss, with the guard's squared update norm when it
+      tracks one); registered with an enabled telemetry.
     * ``memwatch`` — :class:`repro.telemetry.MemoryWatermark`; sampled after
       every successful step.
     * ``pressure`` — :class:`repro.runtime.degrade.WatermarkTrigger`; fed
@@ -410,6 +412,8 @@ class ResilientLoop:
         :class:`StepResult`), or rewinds, retries or restores."""
         tel = self.telemetry
         t0 = time.monotonic()
+        track_norm = (self.guard is not None
+                      and self.guard.track_update_norm)
         try:
             if self.injector is not None:
                 self.injector.before_step(self.step)
@@ -418,9 +422,14 @@ class ResilientLoop:
             with tel.span("train/dispatch"):
                 new_params, new_opt, loss = self.step_fn(
                     self.params, self.opt_state, batch)
+                # queued behind the step, read with the loss below
+                sq_norm = (update_sq_norm(self.params, new_params)
+                           if track_norm else None)
             if self.injector is not None:
                 loss = self.injector.after_step(self.step, loss)
             with tel.span("train/loss_sync"):
+                if track_norm:
+                    loss, sq_norm = jax.device_get((loss, sq_norm))
                 lossf = float(loss)
             self._syncs.inc()
         except (KeyboardInterrupt, SystemExit):
@@ -430,9 +439,7 @@ class ResilientLoop:
             return
         if self.guard is not None:
             with tel.span("train/guard"):
-                unorm = (update_norm(self.params, new_params,
-                                     syncs=self._syncs)
-                         if self.guard.track_update_norm else None)
+                unorm = math.sqrt(sq_norm) if track_norm else None
                 verdict = self.guard.observe(lossf, update_norm=unorm,
                                              step=self.step)
             if verdict == "reject":

@@ -38,20 +38,36 @@ class GuardExhausted(RuntimeError):
     """Raised when a run rejects more steps than its guard budget allows."""
 
 
+@jax.jit
+def _sq_norm(old_leaves, new_leaves):
+    # convert, subtract, square and sum fuse into one reduction per leaf:
+    # no float32 copy of a leaf is materialised
+    total = jnp.zeros((), jnp.float32)
+    for a, b in zip(old_leaves, new_leaves):
+        d = b.astype(jnp.float32) - a.astype(jnp.float32)
+        total = total + jnp.sum(d * d)
+    return total
+
+
+def update_sq_norm(old_params, new_params) -> jax.Array:
+    """Squared global L2 norm of the parameter update over float leaves
+    (frozen int8 leaves are unchanged and skipped), dispatched as one
+    jitted program and left on the device: the caller reads it, with
+    whatever else it reads. Inputs are not donated — a rejected step
+    keeps ``old_params``. Compiles once per tree structure and shapes."""
+    pairs = [(a, b) for a, b in zip(jax.tree_util.tree_leaves(old_params),
+                                    jax.tree_util.tree_leaves(new_params))
+             if jnp.issubdtype(jnp.result_type(a), jnp.inexact)]
+    return _sq_norm([a for a, _ in pairs], [b for _, b in pairs])
+
+
 def update_norm(old_params, new_params, syncs=None) -> float:
-    """Global L2 norm of the parameter update over float leaves (LoRA
-    factors; frozen int8 leaves are unchanged and skipped). Each float
-    leaf's sum is read back to the host on its own; ``syncs`` (a
-    :class:`~repro.telemetry.metrics.Counter`) counts those reads."""
-    total = 0.0
-    for a, b in zip(jax.tree_util.tree_leaves(old_params),
-                    jax.tree_util.tree_leaves(new_params)):
-        if not jnp.issubdtype(jnp.asarray(a).dtype, jnp.inexact):
-            continue
-        d = (jnp.asarray(b, jnp.float32) - jnp.asarray(a, jnp.float32))
-        total += float(jnp.sum(d * d))
-        if syncs is not None:
-            syncs.inc()
+    """Global L2 norm of the parameter update over float leaves: one
+    program (:func:`update_sq_norm`) and one read back to the host, which
+    ``syncs`` (a :class:`~repro.telemetry.metrics.Counter`) counts."""
+    total = float(update_sq_norm(old_params, new_params))
+    if syncs is not None:
+        syncs.inc()
     return math.sqrt(total)
 
 

@@ -50,12 +50,6 @@ def _host_events(log_dir, names):
     return sorted(out, key=lambda e: (e[1], -e[2]))
 
 
-def _float_leaves(tr):
-    shapes = tr._state_struct(tr.live_spec)[0]
-    return sum(jnp.issubdtype(s.dtype, jnp.inexact)
-               for s in jax.tree_util.tree_leaves(shapes))
-
-
 def test_fit_spans_sit_in_their_step_in_loop_order(tmp_path):
     """Telemetry off, guard on: every step attempt is one "train" step
     annotation holding each loop phase once, in loop order."""
@@ -79,15 +73,52 @@ def test_fit_spans_sit_in_their_step_in_loop_order(tmp_path):
     outside = [e[0] for e in events if e[0] != "train"
                and not any(s0 <= e[1] <= s1 for _, s0, s1, _ in steps)]
     assert outside == ["train/restore", "train/checkpoint"]
-    # one read of the loss a step, and one for each float leaf's norm
-    assert dict(tr.counters) == {
-        "steps": 3, "host_syncs": 3 * (1 + _float_leaves(tr))}
+    # one read a step: the loss together with the guard's update norm
+    assert dict(tr.counters) == {"steps": 3, "host_syncs": 3 * 1}
 
 
 def test_host_syncs_without_the_guard(tmp_path):
     tr = Trainer.from_spec(_tiny_spec(tmp_path, guard="off", steps=2))
     tr.fit()
     assert dict(tr.counters) == {"steps": 2, "host_syncs": 2}
+
+
+def test_nan_loss_step_is_rejected_and_rewound(tmp_path):
+    """A fault-injected NaN loss is still rejected and rewound with the
+    guard's norm read beside the loss: the update is discarded, its batch
+    skipped, one guard skip counted, and every attempt reads the host
+    once."""
+    from repro.checkpoint import Checkpointer
+    from repro.data import make_batch_iterator
+    from repro.runtime.fault_tolerance import ResilientLoop
+    from repro.runtime.faults import FaultInjector, FaultPlan
+    from repro.runtime.guard import StepGuard
+
+    def init_state():
+        return {"w": jnp.zeros((4,), jnp.float32),
+                "b": jnp.zeros((3,), jnp.bfloat16),
+                "q": jnp.ones((2,), jnp.int8)}, None
+
+    def step_fn(params, opt_state, batch):
+        new = {"w": params["w"] + 1.0, "b": params["b"] + 1.0,
+               "q": params["q"]}
+        return new, opt_state, jnp.float32(1.0)
+
+    guard = StepGuard(warmup=100)
+    loop = ResilientLoop(
+        step_fn, init_state, make_batch_iterator(50, 4, 2, n_tokens=2048),
+        Checkpointer(str(tmp_path / "ckpt"), interval=100), 3,
+        guard=guard, injector=FaultInjector(FaultPlan.parse("nan@1")))
+    params, _, results, counters = loop.run()
+    assert counters.guard_skips == 1
+    assert guard.state()["by_reason"]["nonfinite_loss"] == 1
+    assert [r.step for r in results] == [1, 2, 3]
+    # three updates kept of four computed
+    assert float(params["w"][0]) == 3.0 and float(params["b"][0]) == 3.0
+    assert int(params["q"][0]) == 1
+    # the norm of each accepted update: sqrt(4 + 3)
+    assert abs(guard.state()["norm_ewma"] - 7 ** 0.5) < 1e-6
+    assert dict(loop.train_counters) == {"steps": 3, "host_syncs": 4}
 
 
 def test_enabled_fit_records_the_loop_spans(tmp_path):
@@ -104,7 +135,7 @@ def test_enabled_fit_records_the_loop_spans(tmp_path):
     assert spans["train/dispatch"]["count"] == 2
     reg = result.metrics["registry"]
     assert reg["train.steps"] == 2
-    assert reg["train.host_syncs"] == 2 * (1 + _float_leaves(tr))
+    assert reg["train.host_syncs"] == 2 * 1
     # the report lists trace.json's spans in the loop's order
     import importlib.util
     path = os.path.join(os.path.dirname(os.path.dirname(__file__)),

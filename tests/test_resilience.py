@@ -18,7 +18,8 @@ from repro.runtime.fault_tolerance import (ResilientLoop, StragglerPolicy,
                                            run_resilient)
 from repro.runtime.faults import (FaultInjector, FaultPlan, InjectedOOM,
                                   corrupt_latest_checkpoint, is_oom_error)
-from repro.runtime.guard import GuardExhausted, StepGuard
+from repro.runtime.guard import (GuardExhausted, StepGuard, _sq_norm,
+                                 update_norm)
 
 
 # ---------------------------------------------------------------- FaultPlan
@@ -107,6 +108,80 @@ def test_guard_rejects_update_norm_spike():
     assert g.observe(1.0, update_norm=0.1) == "accept"
     assert g.observe(1.0, update_norm=0.1) == "accept"
     assert g.observe(1.0, update_norm=5.0) == "reject"
+
+
+def _norm_trees(seed=0, nan=False):
+    """Old and new params: bf16, f32 and int8 leaves (the int8 ones
+    changed too, which the norm must not see)."""
+    rng = np.random.default_rng(seed)
+
+    def tree():
+        return {"base": {"w": jnp.asarray(rng.normal(size=(16, 8)),
+                                          jnp.bfloat16),
+                         "q": jnp.asarray(rng.integers(-100, 100, (16, 8)),
+                                          jnp.int8)},
+                "lora": {"a": jnp.asarray(rng.normal(size=(8, 4)),
+                                          jnp.float32),
+                         "b": jnp.asarray(rng.normal(size=(4, 16)),
+                                          jnp.bfloat16)}}
+    old, new = tree(), tree()
+    if nan:
+        new["lora"]["a"] = new["lora"]["a"].at[1, 2].set(jnp.nan)
+    return old, new
+
+
+def _eager_norm(old, new):
+    """The per-leaf sum the guard used to compute, leaf by leaf."""
+    total = 0.0
+    for a, b in zip(jax.tree_util.tree_leaves(old),
+                    jax.tree_util.tree_leaves(new)):
+        if not jnp.issubdtype(a.dtype, jnp.inexact):
+            continue
+        d = jnp.asarray(b, jnp.float32) - jnp.asarray(a, jnp.float32)
+        total += float(jnp.sum(d * d))
+    return total ** 0.5
+
+
+def test_update_norm_matches_the_eager_per_leaf_sum():
+    from repro.telemetry.metrics import Counter
+    old, new = _norm_trees()
+    syncs = Counter()
+    got = update_norm(old, new, syncs=syncs)
+    np.testing.assert_allclose(got, _eager_norm(old, new), rtol=1e-5)
+    assert syncs.value == 1          # one read back, whatever the leaves
+
+
+def test_update_norm_skips_int8_leaves():
+    old, new = _norm_trees()
+    floats_only = lambda t: {"w": t["base"]["w"], **t["lora"]}
+    np.testing.assert_allclose(
+        update_norm(old, new),
+        update_norm(floats_only(old), floats_only(new)), rtol=1e-6)
+    same = {"q": jnp.zeros((3,), jnp.int8)}
+    assert update_norm(same, {"q": jnp.full((3,), 9, jnp.int8)}) == 0.0
+
+
+def test_nan_leaf_gives_a_nonfinite_norm_rejection():
+    old, new = _norm_trees(nan=True)
+    norm = update_norm(old, new)
+    assert not np.isfinite(norm)
+    g = StepGuard(budget=3)
+    assert g.observe(1.0, update_norm=norm) == "reject"
+    assert g.by_reason["nonfinite_norm"] == 1
+
+
+def test_update_norm_compiles_once_per_tree_structure():
+    old, new = _norm_trees(seed=1)
+    old["lora"]["c"] = jnp.ones((5, 3), jnp.float32)   # a shape of its own
+    new["lora"]["c"] = jnp.zeros((5, 3), jnp.float32)
+    n0 = _sq_norm._cache_size()
+    update_norm(old, new)
+    assert _sq_norm._cache_size() == n0 + 1
+    update_norm(new, old)
+    old2, new2 = _norm_trees(seed=2)
+    old2["lora"]["c"], new2["lora"]["c"] = old["lora"]["c"], new["lora"]["c"]
+    update_norm(old2, new2)
+    assert _sq_norm._cache_size() == n0 + 1
 
 
 # ---------------------------------------------------------------- straggler
