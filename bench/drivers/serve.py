@@ -22,7 +22,6 @@ import numpy as np
 from bench import arrivals, device, weights
 from bench import spec as spec_mod
 from bench import trace as trace_mod
-from bench.flops import Widths
 
 
 def _p95(xs):
@@ -31,23 +30,24 @@ def _p95(xs):
 
 
 def run(*, conf, traffic, seed, seconds, trace_dir=None, fault=None,
-        t_start, rate=None):
+        t_start, rate=None, root=spec_mod.ROOT):
     import jax
     import jax.numpy as jnp
 
     from repro.api import TrainSpec
     from repro.serve import AdapterStore, ContinuousBatcher, Request
 
-    w = Widths.from_config(conf)
-    cfg = spec_mod.arch_config(conf)
+    arch = spec_mod.load_arch(conf, root)
+    w = arch.Widths.from_config(conf)
+    cfg = spec_mod.arch_config(conf, root)
     traffic = dict(traffic, rate_per_s=rate or traffic["rate_per_s"])
     policy = TrainSpec(arch=conf["arch"], engine=traffic["engine"]).policy()
     device.refuse_interpret(policy)
 
     key = weights.root_key(seed)
-    base = weights.make_base(w, key, cfg.dtype)
-    params = weights.to_program(
-        base, weights.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype), w)
+    base = arch.make_base(w, key, cfg.dtype)
+    params = arch.to_program(
+        base, arch.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype), w)
     b_std = traffic["adapter_b_std"]
     tenant_key = lambda t: jax.random.fold_in(key, 100 + t)  # noqa: E731
     store = AdapterStore(params, capacity=traffic["store_capacity"])
@@ -55,7 +55,7 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, fault=None,
                             tile=traffic["tile"], max_len=traffic["max_len"],
                             page_size=traffic["page_size"], policy=policy)
     for t in range(traffic["tenants"]):
-        bat.register_adapter(f"t{t}", weights.lora_tree(weights.make_lora(
+        bat.register_adapter(f"t{t}", arch.lora_tree(arch.make_lora(
             w, tenant_key(t), cfg.dtype, b_std)))
     del params
 
@@ -175,17 +175,15 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, fault=None,
         longest among them); the widest gap by which a served token's
         logit lies below the reference's best. With ``control``, the gap
         of the token that the int8 reference puts first instead."""
-        from bench.reference import Reference, quantize_int8
-
         sample = _sample(done, traffic["check"], seed)
-        ref = Reference(conf)
-        base = weights.make_base(w, key, cfg.dtype)
-        lower = quantize_int8(base, jnp.float32) if control else None
+        ref = arch.Reference(conf)
+        base = arch.make_base(w, key, cfg.dtype)
+        lower = arch.quantize_int8(base, jnp.float32) if control else None
         gaps = []
         for rid in sample:
             tenant, prompt = prompts[rid]
             served = done[rid]
-            lo = weights.make_lora(w, tenant_key(tenant), cfg.dtype, b_std)
+            lo = arch.make_lora(w, tenant_key(tenant), cfg.dtype, b_std)
             seq = np.zeros(traffic["max_len"], np.int32)
             full = list(prompt) + served
             seq[:len(full)] = full

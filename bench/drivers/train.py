@@ -23,7 +23,6 @@ from bench import check, device, weights
 from bench import spec as spec_mod
 from bench import trace as trace_mod
 from bench.data import Windows
-from bench.flops import Widths
 
 CHECK_STEPS = 3
 WARM_STEPS = 5
@@ -50,7 +49,7 @@ def _half_batch(batch):
 
 
 def run(*, conf, traffic, seed, seconds, trace_dir=None, quantize=None,
-        fault=None, t_start):
+        fault=None, t_start, root=spec_mod.ROOT):
     """One run of a training cell. Returns the readings, and ``verify``,
     which runs the reference once the program's state is freed (on return
     from here) and gives the numbers compared."""
@@ -60,8 +59,9 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, quantize=None,
     from repro.core.quant import quantize_params
     from repro.models import model as model_lib
 
-    w = Widths.from_config(conf)
-    cfg = spec_mod.arch_config(conf)
+    arch = spec_mod.load_arch(conf, root)
+    w = arch.Widths.from_config(conf)
+    cfg = spec_mod.arch_config(conf, root)
     quantize = quantize or traffic["quantize"]
     batch, seq, lr = traffic["batch"], traffic["seq"], traffic["lr"]
     ckpt = tempfile.mkdtemp(prefix="bench_ckpt_")
@@ -75,10 +75,10 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, quantize=None,
     device.refuse_interpret(tr.policy)
 
     key = weights.root_key(seed)
-    base = weights.make_base(w, key, cfg.dtype)
-    lora0 = weights.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype)
+    base = arch.make_base(w, key, cfg.dtype)
+    lora0 = arch.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype)
     p0 = _host(lora0)
-    params = weights.to_program(base, lora0, w)
+    params = arch.to_program(base, lora0, w)
     if quantize != "none":
         params = quantize_params(params, quantize)
     want = jax.eval_shape(lambda: model_lib.init_params(
@@ -127,9 +127,9 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, quantize=None,
         if fault == "unchanged":
             out = (p, o, out[2])
         if k == 0:
-            st["p1"] = _host(weights.lora_of(out[0]))
+            st["p1"] = _host(arch.lora_of(out[0]))
         elif k == CHECK_STEPS - 1:
-            st["p3"] = _host(weights.lora_of(out[0]))
+            st["p3"] = _host(arch.lora_of(out[0]))
         return out
 
     tr.step_fn = step_fn
@@ -192,15 +192,13 @@ def run(*, conf, traffic, seed, seconds, trace_dir=None, quantize=None,
         """The reference over the first steps' batches, once the program's
         state is gone; returns the numbers compared. With ``control``, the
         reference on an int8-rounded base stands in the program's place."""
-        from bench.reference import Reference, quantize_int8
-
-        ref = Reference(conf)
-        base = weights.make_base(w, key, cfg.dtype)
-        lora0 = weights.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype)
+        ref = arch.Reference(conf)
+        base = arch.make_base(w, key, cfg.dtype)
+        lora0 = arch.make_lora(w, jax.random.fold_in(key, 1), cfg.dtype)
         ref_losses, ref_grads, ref_states = ref.sgd(base, lora0, batches, lr)
         got_losses, got_p1, got_p3 = losses, p1, p3
         if control:
-            lower = quantize_int8(base)
+            lower = arch.quantize_int8(base)
             del base        # one copy of the weights beside the reference
             got_losses, _, states = ref.sgd(lower, lora0, batches, lr)
             got_p1, got_p3 = _host(states[0]), _host(states[-1])

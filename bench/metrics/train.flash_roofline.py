@@ -1,8 +1,8 @@
 """Share of their roofline that the flash attention kernels reach in the
 traced window: forward calls at the causal forward's need, each
-``flash_dq``/``flash_dkv`` pair at the backward's (``bench/flops.py``),
+``flash_dq``/``flash_dkv`` pair at the backward's, as the configuration's
+architecture module counts them (``flash_ops``, from ``bench/flops.py``),
 over the kernels' summed device time, in percent."""
-from bench.flops import flash_op
 from bench.peaks import roofline_seconds
 
 
@@ -15,8 +15,8 @@ def read(ctx):
                                             "flash_dkv"))
     if not (nf and nq and nq == nk):
         return None
-    shape = (tr["batch"], w.heads, w.kv_heads, tr["seq"], w.head_dim)
-    need = (nf * roofline_seconds(*flash_op("fwd", *shape), ctx["kind"])
-            + nq * roofline_seconds(*flash_op("bwd", *shape), ctx["kind"]))
+    fwd, bwd = ctx["arch"].flash_ops(w, tr["batch"], tr["seq"])
+    need = (nf * roofline_seconds(*fwd, ctx["kind"])
+            + nq * roofline_seconds(*bwd, ctx["kind"]))
     spent = sum(times[k] for k in ("flash_fwd", "flash_dq", "flash_dkv"))
     return 100.0 * need / (spent / 1e9)

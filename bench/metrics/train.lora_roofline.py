@@ -3,12 +3,14 @@ window: the least time their calls need over their summed device time, in
 percent. Each call's need is that of its op at its logical shapes
 (``bench/flops.py``; the larger of FLOPs over peak and bytes over
 bandwidth): the target it serves is read from its operand and result
-shapes in the trace, which may be padded, taking the configuration's
-target that fits them with the least padding. A call whose operands
-cannot be read is not guessed at: the metric is then not read."""
+shapes in the trace, which may be padded, taking the call of the step
+(the configuration's architecture module, ``lora_calls``: target, rows,
+K, N) that fits them with the least padding, at that call's rows. A call
+whose operands cannot be read is not guessed at: the metric is then not
+read."""
 import re
 
-from bench.flops import lora_op, lora_targets
+from bench.flops import lora_op
 from bench.peaks import roofline_seconds
 
 KERNELS = {"lora_fwd": "fwd", "lora_dx": "dx", "lora_dab": "dab"}
@@ -35,12 +37,14 @@ def _padded_kn(kind, text, rank):
     return (ks[0], ns[0]) if ks and ns else None
 
 
-def _target(kind, text, targets, rank):
+def _call(kind, text, calls, rank):
+    """(M, K, N) of the step's call that fits a traced call's padded
+    shapes with the least padding."""
     kn = _padded_kn(kind, text, rank)
     if kn is None:
         return None
-    fits = [(k, n) for _, k, n in targets if k <= kn[0] and n <= kn[1]]
-    return min(fits, key=lambda t: (kn[0] - t[0]) + (kn[1] - t[1])) \
+    fits = [(m, k, n) for _, m, k, n in calls if k <= kn[0] and n <= kn[1]]
+    return min(fits, key=lambda t: (kn[0] - t[1]) + (kn[1] - t[2])) \
         if fits else None
 
 
@@ -50,14 +54,13 @@ def read(ctx):
     events = ctx["trace"].op_events(t0, t1, KERNELS)
     if not events:
         return None
-    targets = lora_targets(w)
-    m = tr["batch"] * tr["seq"]
+    calls = ctx["arch"].lora_calls(w, tr["batch"], tr["seq"])
     need = spent = 0.0
     for base, text, ns in events:
-        kn = _target(KERNELS[base], text, targets, w.rank)
-        if kn is None:
+        mkn = _call(KERNELS[base], text, calls, w.rank)
+        if mkn is None:
             return None
-        need += roofline_seconds(*lora_op(KERNELS[base], m, kn[0], kn[1],
-                                          w.rank), ctx["kind"])
+        need += roofline_seconds(*lora_op(KERNELS[base], *mkn, w.rank),
+                                 ctx["kind"])
         spent += ns / 1e9
     return 100.0 * need / spent

@@ -4,7 +4,8 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
+(``bench/configs/<config>.json``, which names its architecture module in
+``bench/archs/``) and a traffic mix
 (``bench/traffic/<traffic>.json``, which names its driver in
 ``bench/drivers/``). Inputs and weights come from ``--seed``. Set-up warms
 every shape the window uses; the window then measures for ``--seconds``.
@@ -54,11 +55,11 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool, *,
              root: str = ROOT, fault=None) -> dict:
     """One run of ``workload``; returns the result line's object."""
     from bench import check, device, spec
-    from bench.flops import Widths
 
     bench = spec.load_benchmark(root)
     cell = spec.find_cell(bench, workload)
     conf = spec.load_config(bench, cell["config"], root)
+    arch = spec.load_arch(conf, root)
     traffic = spec.load_traffic(cell["traffic"], root)
     limits = spec.load_limits(workload, root)
     wanted = spec.metrics_of_cell(bench, cell, trace)
@@ -73,7 +74,7 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool, *,
     try:
         got = driver.run(conf=conf, traffic=traffic, seed=seed,
                          seconds=seconds, trace_dir=trace_dir,
-                         fault=fault, t_start=T_START)
+                         fault=fault, t_start=T_START, root=root)
         gc.collect()
         dev = {**device.describe(cell["chips"]),
                "memory_peak_bytes": got["memory_peak_bytes"]}
@@ -90,7 +91,8 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool, *,
                                       "executions of the timed program")
             t0, t1, n = win
             ctx = {"trace": tr, "module": got["module"], "window": win,
-                   "widths": Widths.from_config(conf), "traffic": traffic,
+                   "widths": arch.Widths.from_config(conf), "arch": arch,
+                   "traffic": traffic,
                    "kind": dev["kind"], "peaks": peaks.peaks(dev["kind"]),
                    "counters": got.get("counters", {})}
             for m in wanted:

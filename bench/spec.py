@@ -1,12 +1,15 @@
 """Resolve a cell of ``BENCHMARK.json`` to its files.
 
 A cell names a configuration and a traffic mix. The configuration's file
-(``bench/configs/<config>.json``) holds the published widths; the traffic
+(``bench/configs/<config>.json``) holds the published widths and names,
+under ``architecture``, the module of its architecture
+(``bench/archs/<name>.py``: weights, op counts, reference); the traffic
 file (``bench/traffic/<traffic>.json``) the mix and the driver that runs
 it; ``bench/limits/<cell>.json`` the limits of the correctness check; and
 ``bench/metrics/<metric>.py`` the reader of each per-layer metric. The
-harness finds all of them by name, so a new cell or metric is new files
-and entries, never an edit.
+harness finds all of them by name or path, so a new cell, metric or
+configuration, of any architecture, is new files and entries, never an
+edit.
 """
 from __future__ import annotations
 
@@ -14,19 +17,10 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
-
-# config.json key -> ArchConfig field of the program's registry
-HF_TO_ARCH = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "num_hidden_layers": "n_layers", "vocab_size": "vocab",
-    "tie_word_embeddings": "tie_embeddings", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "torch_dtype": "dtype",
-}
-ASSUMED_TO_ARCH = {"head_dim": "head_dim", "attention_bias": "qkv_bias"}
 
 
 class CellError(Exception):
@@ -71,38 +65,92 @@ def load_limits(workload: str, root: str = ROOT) -> dict:
                                    f"{workload}.json"))["limits"]
 
 
-def arch_config(conf: dict):
-    """The program's ArchConfig for ``conf``: the registry's entry, checked
-    key by key against the published widths. A key listed in ``reduced``
-    is taken from the file; any other key that differs is an error."""
+def load_arch(conf: dict, root: str = ROOT):
+    """The architecture module that ``conf`` names under ``architecture``,
+    loaded by path, once per path. It holds:
+
+    * ``HF_TO_ARCH`` and ``ASSUMED_TO_ARCH``: the keys of the file's
+      ``published`` and ``assumed`` sections that the registry holds, each
+      mapped to its ``ArchConfig`` field (``group.field`` inside a nested
+      group, such as ``moe.top_k``);
+    * ``refuse(cfg)``: raises :class:`CellError` for a registry entry with
+      settings the module does not model;
+    * ``Widths.from_config(conf)``, the sizes, with the LoRA ``rank``;
+    * ``make_base(w, key, dtype)``, ``make_lora(w, key, dtype, b_std=0.0)``,
+      ``to_program(base, lora, w)``, ``lora_tree(lora)``, ``lora_of(params)``;
+    * ``train_flops_per_token(w, seq)``, ``lora_calls(w, batch, seq)``
+      (target, M, K, N of each LoRA linear) and ``flash_ops(w, batch,
+      seq)`` (FLOPs and bytes of one flash forward call and one
+      backward), for the readers;
+    * ``Reference(conf)`` (``sgd``, ``logits``) and ``quantize_int8``.
+    """
+    path = os.path.abspath(os.path.join(root, conf["architecture"]))
+    name = "bench_arch:" + path
+    if name in sys.modules:
+        return sys.modules[name]
+    if not os.path.isfile(path):
+        raise CellError(f"{conf['name']}: no architecture module "
+                        f"{conf['architecture']}")
+    modspec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(modspec)
+    sys.modules[name] = mod        # dataclasses look their module up here
+    modspec.loader.exec_module(mod)
+    return mod
+
+
+def _field(obj, field: str):
+    """A registry field by dotted path, at its resolved value where the
+    config derives one (``resolved_head_dim`` for ``head_dim``)."""
+    head, _, rest = field.partition(".")
+    if rest:
+        return _field(getattr(obj, head), rest)
+    return getattr(obj, "resolved_" + head, getattr(obj, head))
+
+
+def _replace(obj, changes: dict):
+    """``obj`` with the dotted fields of ``changes`` replaced."""
+    top, nested = {}, {}
+    for field, value in changes.items():
+        head, _, rest = field.partition(".")
+        if rest:
+            nested.setdefault(head, {})[rest] = value
+        else:
+            top[head] = value
+    for head, sub in nested.items():
+        top[head] = _replace(getattr(obj, head), sub)
+    return dataclasses.replace(obj, **top)
+
+
+def arch_config(conf: dict, root: str = ROOT):
+    """The program's ArchConfig for ``conf``: the registry's entry, refused
+    where the architecture module does not model it, and checked key by
+    key against the published widths. A key listed in ``reduced`` is
+    taken from the file; any other key that differs is an error."""
     from repro.configs import get_config
 
+    arch = load_arch(conf, root)
     cfg = get_config(conf["arch"])
-    want = {}
-    for key, field in HF_TO_ARCH.items():
-        want[field] = conf["published"][key]
-    for key, field in ASSUMED_TO_ARCH.items():
-        want[field] = conf["assumed"][key]
+    arch.refuse(cfg)
+    keys = [(conf[section][key], key, field)
+            for section, keymap in (("published", arch.HF_TO_ARCH),
+                                    ("assumed", arch.ASSUMED_TO_ARCH))
+            for key, field in keymap.items()]
     lora = conf["assumed"]["lora"]
     reduced = set(conf.get("reduced", ()))
     changes = {}
-    for key, field in {**HF_TO_ARCH, **ASSUMED_TO_ARCH}.items():
-        have = getattr(cfg, field)
-        if field == "head_dim":
-            have = cfg.resolved_head_dim
-        if have != want[field]:
+    for want, key, field in keys:
+        have = _field(cfg, field)
+        if have != want:
             if key not in reduced:
-                raise CellError(f"{conf['name']}: {key} is {want[field]!r} "
+                raise CellError(f"{conf['name']}: {key} is {want!r} "
                                 f"in the file but {have!r} in the program's "
                                 f"registry ({conf['arch']})")
-            changes[field] = want[field]
+            changes[field] = want
     if (cfg.lora.rank, cfg.lora.alpha, tuple(cfg.lora.targets)) != (
             lora["rank"], lora["alpha"], tuple(lora["targets"])):
         raise CellError(f"{conf['name']}: LoRA setting differs from the "
                         f"registry's {cfg.lora}")
-    if cfg.family != "dense" or cfg.window_pattern:
-        raise CellError(f"{conf['arch']} is not a dense decoder")
-    return dataclasses.replace(cfg, **changes) if changes else cfg
+    return _replace(cfg, changes) if changes else cfg
 
 
 def metrics_of_cell(bench: dict, cell: dict, trace: bool) -> list:

@@ -1,6 +1,7 @@
 """Fixtures of the benchmark's CPU tests: a root holding the tiny cells of
-``data/tiny`` beside the benchmark's own readers, and a harness whose look
-for a chip is switched off (the rest of a run is driven as on the chip)."""
+``data/tiny`` beside the benchmark's own readers and architecture modules,
+and a harness whose look for a chip is switched off (the rest of a run is
+driven as on the chip)."""
 import os
 import shutil
 import sys
@@ -19,8 +20,9 @@ def tiny_root(tmp_path_factory):
     root = tmp_path_factory.mktemp("benchroot")
     shutil.copytree(os.path.join(REPO, "tests", "bench", "data", "tiny"),
                     root, dirs_exist_ok=True)
-    shutil.copytree(os.path.join(REPO, "bench", "metrics"),
-                    root / "bench" / "metrics")
+    for sub in ("metrics", "archs"):
+        shutil.copytree(os.path.join(REPO, "bench", sub),
+                        root / "bench" / sub)
     return str(root)
 
 

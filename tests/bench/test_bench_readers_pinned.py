@@ -7,7 +7,6 @@ import os
 import pytest
 
 from bench import peaks, spec
-from bench.flops import Widths
 from bench.trace import Trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -29,10 +28,11 @@ def ctx():
     tr = Trace(rec["reduced"])
     bench = spec.load_benchmark()
     cell = spec.find_cell(bench, CELL)
+    conf = spec.load_config(bench, cell["config"])
+    arch = spec.load_arch(conf)
     return {"trace": tr, "module": rec["module"],
             "window": tr.window(rec["module"]),
-            "widths": Widths.from_config(spec.load_config(bench,
-                                                          cell["config"])),
+            "widths": arch.Widths.from_config(conf), "arch": arch,
             "traffic": spec.load_traffic(cell["traffic"]),
             "kind": "TPU v5 lite", "peaks": peaks.peaks("TPU v5 lite"),
             "counters": {}}

@@ -79,17 +79,17 @@ def test_readers_on_recorded_chip_trace():
     """The training readers on the recorded v5e trace: shares of a peak
     or a roofline stay within 100 %."""
     from bench import peaks, spec
-    from bench.flops import Widths
 
     with open(os.path.join(DATA, "trace_v5e_05b.json")) as f:
         rec = json.load(f)
     tr = Trace(rec["reduced"])
     bench = spec.load_benchmark()
     cell = spec.find_cell(bench, "qwen2.5-0.5b.ft.paper")
+    conf = spec.load_config(bench, cell["config"])
+    arch = spec.load_arch(conf)
     ctx = {"trace": tr, "module": rec["module"],
            "window": tr.window(rec["module"]),
-           "widths": Widths.from_config(spec.load_config(bench,
-                                                         cell["config"])),
+           "widths": arch.Widths.from_config(conf), "arch": arch,
            "traffic": spec.load_traffic(cell["traffic"]),
            "kind": "TPU v5 lite", "peaks": peaks.peaks("TPU v5 lite"),
            "counters": {}}
