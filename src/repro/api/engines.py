@@ -15,19 +15,25 @@ from repro.api.registry import register_engine
 
 def _grad_builder(spec, cfg, opt, policy):
     """Shared step-builder for engines that are `mesp.value_and_grad` under
-    a specific ExecutionPolicy backend."""
+    a specific ExecutionPolicy backend. A model with counters
+    (``model.aux_names``: the ``moe`` family's expert counters) gets a step
+    that returns them fourth, to be read with the loss."""
     from repro.core import mesp
+    from repro.models import model as model_lib
 
-    def vag(params, batch):
-        return mesp.value_and_grad(params, cfg, batch, policy=policy)
+    fn = (mesp.value_grad_and_aux if model_lib.aux_names(cfg)
+          else mesp.value_and_grad)
+
+    def vag(params, batch, policy=policy):
+        return fn(params, cfg, batch, policy=policy)
 
     if policy.backend == "pallas":
         vag = _kernel_data_parallel(vag, cfg, policy)
 
     def step(params, opt_state, batch):
-        loss, grads = vag(params, batch)
+        loss, grads, *aux = vag(params, batch)
         params, opt_state = opt.update(grads, opt_state, params)
-        return params, opt_state, loss
+        return (params, opt_state, loss, *aux)
 
     return step
 
@@ -49,7 +55,6 @@ def _kernel_data_parallel(vag, cfg, policy):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.core import mesp
     from repro.models.layers import ambient_mesh
 
     bdim = policy.act_spec[0]
@@ -62,14 +67,17 @@ def _kernel_data_parallel(vag, cfg, policy):
             return vag(params, batch)
 
         def shard(params, batch):
-            out = mesp.value_and_grad(params, cfg, batch, policy=local_policy)
-            return jax.lax.pmean(out, bdim) if bdim is not None else out
+            out = vag(params, batch, policy=local_policy)
+            if bdim is None:
+                return out
+            # loss and grads averaged over the data shards, counters summed
+            return (*jax.lax.pmean(out[:2], bdim),
+                    *jax.lax.psum(out[2:], bdim))
 
         # check_vma off: pallas_call outputs carry no varying-axes info;
-        # every output here is replicated
+        # every output here is replicated (one spec, a prefix of them all)
         return jax.shard_map(shard, mesh=mesh, in_specs=(P(), P(bdim)),
-                             out_specs=(P(), P()),
-                             check_vma=False)(params, batch)
+                             out_specs=P(), check_vma=False)(params, batch)
 
     return run
 

@@ -75,7 +75,12 @@ class Trainer:
     ``counters`` (``train.steps``, ``train.host_syncs``) count over every
     ``fit`` of this trainer: steps completed and the loop's device→host
     reads (one a step: the loss, with the guard's update norm while the
-    guard runs).
+    guard runs). ``moe_counters`` (``moe.routed_rows``,
+    ``moe.computed_rows``, ``moe.dropped``; ``None`` for a model without
+    counters, ``model.aux_names``) sum the expert layers' counters over
+    the committed steps: a gradient engine's step returns them beside the
+    loss and the loop reads them in the same sync. The zeroth-order
+    engines' steps return none, and leave them at zero.
     """
 
     def __init__(self, spec: TrainSpec, *, cfg=None, mesh=None):
@@ -93,6 +98,9 @@ class Trainer:
         self.opt = make_optimizer(spec.optimizer, constant(spec.lr))
         self.mesh = mesh if mesh is not None else self._auto_mesh(self.spec)
         self.counters = CounterGroup("train", ("steps", "host_syncs"))
+        from repro.models.model import aux_names
+        names = aux_names(cfg)
+        self.moe_counters = CounterGroup("moe", names) if names else None
         self._live_spec: Optional[TrainSpec] = None
         self._switch_to(self.spec)
 
@@ -193,8 +201,9 @@ class Trainer:
 
         With a mesh, the step is jitted with explicit in/out shardings
         (params/opt state on ``launch/sharding.py``'s logical specs, batch
-        on the DP axes, loss replicated) and wrapped to run inside the mesh
-        context so ``with_sharding_constraint``/``mesh_axis_size`` see it."""
+        on the DP axes; the loss, and the counters a step may return after
+        it, replicated) and wrapped to run inside the mesh context so
+        ``with_sharding_constraint``/``mesh_axis_size`` see it."""
         spec = self._with_mesh_act_spec(spec)
         if spec == self._live_spec:
             return
@@ -217,14 +226,22 @@ class Trainer:
             bdim = bspec[0] if len(bspec) else None
             # pytree prefix: every batch leaf shards its leading (batch) dim
             bshard = NamedSharding(mesh, P(bdim))
+            def packed(params, opt_state, batch, _b=build):
+                # the loss and whatever the step returns after it (a
+                # model's counters) as one output, so that one replicated
+                # sharding covers them whatever their number
+                params, opt_state, *rest = _b(params, opt_state, batch)
+                return params, opt_state, tuple(rest)
+
             jitted = jax.jit(
-                build, in_shardings=(pshard, oshard, bshard),
+                packed, in_shardings=(pshard, oshard, bshard),
                 out_shardings=(pshard, oshard, NamedSharding(mesh, P())))
             self._state_shardings = (pshard, oshard)
 
             def step_fn(params, opt_state, batch, _j=jitted, _m=mesh):
                 with _m:
-                    return _j(params, opt_state, batch)
+                    params, opt_state, rest = _j(params, opt_state, batch)
+                return (params, opt_state, *rest)
 
         self.engine, self.policy, self.step_fn = engine, policy, step_fn
         #: the raw jitted step (no mesh-context wrapper) — ``.lower()`` this
@@ -477,7 +494,8 @@ class Trainer:
             straggler=straggler, guard=guard, injector=injector,
             on_step=_log_step, on_oom=on_oom, restore_fn=restore_fn,
             extra_fn=extra_fn, telemetry=tel, memwatch=memwatch,
-            pressure=pressure, train_counters=self.counters)
+            pressure=pressure, train_counters=self.counters,
+            aux_counters=self.moe_counters)
         if tel.enabled:
             tel.emit(tele.RunEvent(
                 phase="start", engine=spec0.engine, quantize=spec0.quantize,

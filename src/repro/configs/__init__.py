@@ -10,6 +10,7 @@ from .base import (
     EncDecConfig,
     HybridConfig,
     LoRAConfig,
+    MLAConfig,
     MoEConfig,
     ShapeConfig,
     SHAPES,
@@ -28,6 +29,7 @@ from . import (
     rwkv6_1_6b,
     recurrentgemma_2b,
     qwen2_5_paper,
+    moonlight_16b_a3b,
 )
 
 _MODULES = [
@@ -38,7 +40,7 @@ _MODULES = [
 REGISTRY = {}
 for _m in _MODULES:
     REGISTRY[_m.CONFIG.name] = _m.CONFIG
-for _c in qwen2_5_paper.CONFIGS:
+for _c in qwen2_5_paper.CONFIGS + moonlight_16b_a3b.CONFIGS:
     REGISTRY[_c.name] = _c
 
 ASSIGNED = tuple(m.CONFIG.name for m in _MODULES)
@@ -51,7 +53,7 @@ def get_config(name: str) -> ArchConfig:
 
 
 __all__ = [
-    "ArchConfig", "LoRAConfig", "MoEConfig", "HybridConfig", "EncDecConfig",
+    "ArchConfig", "LoRAConfig", "MLAConfig", "MoEConfig", "HybridConfig", "EncDecConfig",
     "ShapeConfig", "SHAPES", "shape_applicable", "REGISTRY", "ASSIGNED",
     "get_config",
 ]

@@ -33,11 +33,61 @@ class LoRAConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts of a sparse FFN.
+
+    ``scoring`` turns router logits into scores (``softmax`` or
+    ``sigmoid``); with ``bias_select`` a frozen per-expert correction bias
+    is added to the scores to choose the top-k experts (DeepSeek-V3's
+    ``noaux_tc``), while the combine weights are the scores without it.
+    The chosen weights are divided by their sum and ``routed_scale``
+    multiplies them. Group-limited routing is not modelled (one group).
+
+    ``held_lo``/``n_held``: the experts this device holds, ``[held_lo,
+    held_lo + n_held)`` (``n_held`` 0: all of them) — the expert-parallel
+    share. The router scores all ``n_experts``; the layer computes its own
+    experts' part of the result. ``d_dense``: width of the leading dense
+    FFN (0: ``d_expert·(top_k + n_shared)``).
+    """
+
     n_experts: int
     top_k: int
     d_expert: int
     n_shared: int = 0
     first_layer_dense: bool = False  # deepseek-moe: layer 0 is a dense FFN
+    scoring: str = "softmax"
+    bias_select: bool = False
+    routed_scale: float = 1.0
+    held_lo: int = 0
+    n_held: int = 0
+    d_dense: int = 0
+
+    @property
+    def resolved_n_held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def resolved_d_dense(self) -> int:
+        return self.d_dense or self.d_expert * (self.top_k + self.n_shared)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values come
+    from a ``kv_lora_rank``-wide latent through ``kv_b``; each head's q·k
+    width is a no-rope part plus a rope part, whose key side is one vector
+    per token shared by all heads; v has a width of its own. Only
+    ``q_lora_rank`` None (q projected straight from the hidden state) is
+    modelled."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    q_lora_rank: Optional[int] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -86,6 +136,7 @@ class ArchConfig:
     window_pattern: Tuple[int, ...] = ()  # 0 = global, >0 = local window
 
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     hybrid: Optional[HybridConfig] = None
     encdec: Optional[EncDecConfig] = None
 
@@ -174,11 +225,14 @@ class ArchConfig:
             changes["window_pattern"] = (8, 0)
             changes["n_layers"] = 2
         if self.moe is not None:
-            changes["moe"] = MoEConfig(
-                n_experts=4, top_k=2, d_expert=32,
-                n_shared=min(self.moe.n_shared, 1),
-                first_layer_dense=self.moe.first_layer_dense,
-            )
+            changes["moe"] = dataclasses.replace(
+                self.moe, n_experts=4, top_k=2, d_expert=32,
+                n_shared=min(self.moe.n_shared, 1), held_lo=0,
+                n_held=min(self.moe.n_held, 2),
+                d_dense=64 if self.moe.d_dense else 0)
+        if self.mla is not None:
+            changes["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                                       qk_rope_head_dim=8, v_head_dim=12)
         if self.hybrid is not None:
             changes["hybrid"] = HybridConfig(
                 pattern=self.hybrid.pattern, lru_width=64, window=8

@@ -69,6 +69,21 @@ def value_and_grad(params, cfg: ArchConfig, batch: dict, *,
     return jax.value_and_grad(f)(train)
 
 
+def value_grad_and_aux(params, cfg: ArchConfig, batch: dict, *,
+                       policy: ExecutionPolicy):
+    """(loss, grads, aux): :func:`value_and_grad` plus the model's
+    auxiliary outputs (``model.loss_and_aux``: the expert layers'
+    counters), computed in the same program."""
+    train, frozen = model_lib.split_params(params)
+
+    def f(train):
+        p = model_lib.merge_params(train, frozen)
+        return model_lib.loss_and_aux(p, cfg, batch, policy=policy)
+
+    (loss, aux), grads = jax.value_and_grad(f, has_aux=True)(train)
+    return loss, grads, aux
+
+
 def train_step(params, cfg: ArchConfig, batch: dict, lr: float, *,
                policy: Optional[ExecutionPolicy] = None,
                mode: Optional[str] = None, act_spec=None):

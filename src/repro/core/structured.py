@@ -270,7 +270,8 @@ def _sdpa_mask(Nq: int, Nk: int, window: int, causal: bool, q_offset,
 
 
 def _sdpa_ref(q, k, v, window: int, causal: bool, q_offset, kv_len):
-    """Plain forward. q:[B,H,Nq,D] k,v:[B,Hkv,Nk,D] -> [B,H,Nq,D].
+    """Plain forward. q:[B,H,Nq,D] k:[B,Hkv,Nk,D] v:[B,Hkv,Nk,Dv] ->
+    [B,H,Nq,Dv].
 
     Matmuls run on native (bf16) operands with f32 accumulation
     (``preferred_element_type``) — no materialized f32 copy of K/V, which for
@@ -289,7 +290,7 @@ def _sdpa_ref(q, k, v, window: int, causal: bool, q_offset, kv_len):
     probs = jax.nn.softmax(scores, -1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, H, Nq, D).astype(q.dtype)
+    return out.reshape(B, H, Nq, v.shape[-1]).astype(q.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -311,7 +312,7 @@ def _sdpa_bwd(window, causal, res, g):
     G = H // Hkv
     f32 = dict(preferred_element_type=jnp.float32)
     qg = q.reshape(B, Hkv, G, Nq, D)
-    gg = g.reshape(B, Hkv, G, Nq, D).astype(q.dtype)
+    gg = g.reshape(B, Hkv, G, Nq, v.shape[-1]).astype(q.dtype)
     # --- recompute probs (A.2 forward) ---
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k, **f32) / jnp.sqrt(D)
     mask = _sdpa_mask(Nq, Nk, window, causal, q_offset, kv_len)

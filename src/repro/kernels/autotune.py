@@ -197,10 +197,16 @@ def _heuristic(op: str, dims: Dict[str, int], dtype) -> Dict[str, int]:
         # same residency shape as lora_dab (x[bm,K] + g[bm,N] resident) but
         # bm is fixed by the group layout, so nothing to choose.
         return {}
-    if op == "rmsnorm":
+    if op in ("rmsnorm", "rmsnorm_bwd"):
+        # the backward holds x, g and dx blocks (double-buffered) and its
+        # f32 temporaries: ~19 MB of scoped VMEM at 512 rows of 2048 on a
+        # v5e (16 MB allowed), about 18 bytes an element, so its rows get
+        # a budget of their own
         d = max(dims["d"], 1)
+        per_row = d * (4 if op == "rmsnorm" else 20)
+        budget = _VMEM_BUDGET if op == "rmsnorm" else 14 << 20
         bm = _pick(dims["M"], (512, 256))
-        while bm > 128 and bm * d * 4 > _VMEM_BUDGET:
+        while bm > 128 and bm * per_row > budget:
             bm //= 2
         return {"bm": bm}
     if op == "flash":

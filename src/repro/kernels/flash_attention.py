@@ -31,6 +31,11 @@ GQA is expressed through the schedule + BlockSpec index maps: q rows are
 laid out [B·H, Nq, D], k/v stay [B·Hkv, Nk, D], and the k/v index map
 divides the head program id by the group size — K/V are never repeated.
 
+V may be narrower or wider than q·k (``Dv``; latent attention has q·k 192
+against v 128): v, the output, its gradient and dv are ``Dv`` wide, q, k
+and their gradients ``D``, and the softmax scale is 1/√D. With ``Dv == D``
+every block shape is what it was.
+
 Fused RoPE: with ``rope=(cos, sin)`` ([N, D/2] f32 tables), q/k tiles are
 rotated in VMEM right after load — the rotated q/k never round-trip through
 HBM — and the backward counter-rotates dq/dk (rotation is orthogonal:
@@ -166,9 +171,9 @@ def _fwd_kernel(qi_ref, kj_ref, int_ref, q_ref, k_ref, v_ref, *rest,
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
-              bk: int, causal: bool, window: int, nq: int, nk: int, G: int,
-              fuse_rope: bool, sparse: bool, interpret: bool):
+def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, Dv: int, dtype_name: str,
+              bq: int, bk: int, causal: bool, window: int, nq: int, nk: int,
+              G: int, fuse_rope: bool, sparse: bool, interpret: bool):
     """Construct (pallas_call, schedule) once per static signature — repeated
     non-jit calls (benchmarks, tests) reuse the built closure."""
     qi, kj, it = flash_schedule(Nqp // bq, Nkp // bk, bq, bk, causal,
@@ -183,7 +188,7 @@ def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
         pl.BlockSpec((1, bq, D), lambda b, t, qi, kj, it: (b, qi[t], 0)),
         pl.BlockSpec((1, bk, D),
                      lambda b, t, qi, kj, it: (b // G, kj[t], 0)),
-        pl.BlockSpec((1, bk, D),
+        pl.BlockSpec((1, bk, Dv),
                      lambda b, t, qi, kj, it: (b // G, kj[t], 0)),
     ]
     if fuse_rope:
@@ -200,7 +205,7 @@ def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
             grid=(BH, len(qi)),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, bq, D),
+                pl.BlockSpec((1, bq, Dv),
                              lambda b, t, qi, kj, it: (b, qi[t], 0)),
                 pl.BlockSpec((1, bq, LANE),
                              lambda b, t, qi, kj, it: (b, qi[t], 0)),
@@ -208,11 +213,11 @@ def _fwd_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str, bq: int,
             scratch_shapes=[
                 pltpu.VMEM((bq, 1), jnp.float32),   # running max
                 pltpu.VMEM((bq, 1), jnp.float32),   # running sum
-                pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
+                pltpu.VMEM((bq, Dv), jnp.float32),  # output accumulator
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Nqp, D), dtype),
+            jax.ShapeDtypeStruct((BH, Nqp, Dv), dtype),
             jax.ShapeDtypeStruct((BH, Nqp, LANE), jnp.float32),
         ],
         name="flash_fwd",
@@ -228,7 +233,8 @@ def flash_attention_fwd(q, k, v, rope=None, *, causal: bool = True,
                         window: int = 0, bq: int = 512, bk: int = 512,
                         q_per_kv: int = 1, interpret: bool = False,
                         return_lse: bool = False, sparse: bool = True):
-    """q: [B·H, Nq, D]; k/v: [B·Hkv, Nk, D] with H = Hkv·q_per_kv.
+    """q: [B·H, Nq, D]; k: [B·Hkv, Nk, D]; v: [B·Hkv, Nk, Dv] with
+    H = Hkv·q_per_kv; the output is [B·H, Nq, Dv].
 
     Heads are pre-flattened; consecutive groups of ``q_per_kv`` q heads share
     one kv head (the BlockSpec index map does the division — K/V are never
@@ -243,9 +249,10 @@ def flash_attention_fwd(q, k, v, rope=None, *, causal: bool = True,
     kp = pad_dim(k, bk, 1)
     vp = pad_dim(v, bk, 1)
     Nqp, Nkp = qp.shape[1], kp.shape[1]
-    call, sched = _fwd_call(BH, Nqp, Nkp, D, jnp.dtype(q.dtype).name, bq, bk,
-                            causal, window, Nq, Nk, q_per_kv,
-                            rope is not None, sparse, interpret)
+    call, sched = _fwd_call(BH, Nqp, Nkp, D, v.shape[-1],
+                            jnp.dtype(q.dtype).name, bq, bk, causal, window,
+                            Nq, Nk, q_per_kv, rope is not None, sparse,
+                            interpret)
     operands = [qp, kp, vp]
     if rope is not None:
         cos, sin = rope
@@ -390,10 +397,10 @@ def _bwd_dkv_kernel(kjs_ref, gh_ref, qis_ref, int_ref, q_ref, g_ref, lse_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str,
-                 bq: int, bk: int, causal: bool, window: int, nq: int,
-                 nk: int, G: int, fuse_rope: bool, sparse: bool,
-                 interpret: bool):
+def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, Dv: int,
+                 dtype_name: str, bq: int, bk: int, causal: bool,
+                 window: int, nq: int, nk: int, G: int, fuse_rope: bool,
+                 sparse: bool, interpret: bool):
     qi, kj, it = flash_schedule(Nqp // bq, Nkp // bk, bq, bk, causal,
                                 window, nq, nk, sparse)
     dtype = jnp.dtype(dtype_name)
@@ -406,9 +413,9 @@ def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str,
         pl.BlockSpec((1, bq, D), lambda b, t, qi, kj, it: (b, qi[t], 0)),
         pl.BlockSpec((1, bk, D),
                      lambda b, t, qi, kj, it: (b // G, kj[t], 0)),   # k
-        pl.BlockSpec((1, bk, D),
+        pl.BlockSpec((1, bk, Dv),
                      lambda b, t, qi, kj, it: (b // G, kj[t], 0)),   # v
-        pl.BlockSpec((1, bq, D), lambda b, t, qi, kj, it: (b, qi[t], 0)),  # g
+        pl.BlockSpec((1, bq, Dv), lambda b, t, qi, kj, it: (b, qi[t], 0)),  # g
         pl.BlockSpec((1, bq, LANE),
                      lambda b, t, qi, kj, it: (b, qi[t], 0)),        # lse
         pl.BlockSpec((1, bq, LANE),
@@ -439,10 +446,10 @@ def _bwd_dq_call(BH: int, Nqp: int, Nkp: int, D: int, dtype_name: str,
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, dtype_q: str,
-                  dtype_k: str, dtype_v: str, bq: int, bk: int, causal: bool,
-                  window: int, nq: int, nk: int, G: int, fuse_rope: bool,
-                  sparse: bool, interpret: bool):
+def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, Dv: int,
+                  dtype_q: str, dtype_k: str, dtype_v: str, bq: int, bk: int,
+                  causal: bool, window: int, nq: int, nk: int, G: int,
+                  fuse_rope: bool, sparse: bool, interpret: bool):
     kjs, gh, qis, it = flash_schedule_kv(Nqp // bq, Nkp // bk, bq, bk,
                                          causal, window, nq, nk, G, sparse)
     half = D // 2
@@ -455,11 +462,11 @@ def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, dtype_q: str,
     kvmap = lambda b, t, kjs, gh, qis, it: (b, kjs[t], 0)
     in_specs = [
         pl.BlockSpec((1, bq, D), qmap),        # q
-        pl.BlockSpec((1, bq, D), qmap),        # g
+        pl.BlockSpec((1, bq, Dv), qmap),       # g
         pl.BlockSpec((1, bq, LANE), rmap),     # lse
         pl.BlockSpec((1, bq, LANE), rmap),     # delta
         pl.BlockSpec((1, bk, D), kvmap),       # k
-        pl.BlockSpec((1, bk, D), kvmap),       # v
+        pl.BlockSpec((1, bk, Dv), kvmap),      # v
     ]
     if fuse_rope:
         in_specs += [
@@ -480,16 +487,16 @@ def _bwd_dkv_call(BHkv: int, Nqp: int, Nkp: int, D: int, dtype_q: str,
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, bk, D), kvmap),
-                pl.BlockSpec((1, bk, D), kvmap),
+                pl.BlockSpec((1, bk, Dv), kvmap),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((BHkv, Nkp, D), jnp.dtype(dtype_k)),
-            jax.ShapeDtypeStruct((BHkv, Nkp, D), jnp.dtype(dtype_v)),
+            jax.ShapeDtypeStruct((BHkv, Nkp, Dv), jnp.dtype(dtype_v)),
         ],
         name="flash_dkv",
         interpret=interpret,
@@ -506,7 +513,8 @@ def flash_attention_bwd(q, k, v, out, lse, g, rope=None, *,
                         interpret: bool = False, sparse: bool = True):
     """(dq, dk, dv) from the saved (out, lse) residuals.
 
-    q/g/out: [B·H, Nq, D]; k/v: [B·Hkv, Nk, D]; lse: [B·H, Nq] (f32).
+    q: [B·H, Nq, D]; g/out: [B·H, Nq, Dv]; k: [B·Hkv, Nk, D]; v:
+    [B·Hkv, Nk, Dv]; lse: [B·H, Nq] (f32).
     dk/dv come back group-summed at kv-head layout [B·Hkv, Nk, D]. With
     ``rope=(cos, sin)`` the kernels rotate q/k on load (as the forward did)
     and counter-rotate dq/dk before the final write.
@@ -538,13 +546,15 @@ def flash_attention_bwd(q, k, v, out, lse, g, rope=None, *,
         sinp = _pad_table(sin.astype(jnp.float32), tb, 0.0)
         rope_ops = [cosp, sinp, cosp, sinp]
 
+    Dv = v.shape[-1]
     dq_call, dq_sched = _bwd_dq_call(
-        BH, Nqp, Nkp, D, jnp.dtype(q.dtype).name, bq, bk, causal, window,
+        BH, Nqp, Nkp, D, Dv, jnp.dtype(q.dtype).name, bq, bk, causal, window,
         Nq, Nk, G, rope is not None, sparse, interpret)
     dq = dq_call(*dq_sched, qp, kp, vp, gp, lsep, deltap, *rope_ops)
 
     dkv_call, dkv_sched = _bwd_dkv_call(
-        BHkv, Nqp, Nkp, D, jnp.dtype(q.dtype).name, jnp.dtype(k.dtype).name,
+        BHkv, Nqp, Nkp, D, Dv, jnp.dtype(q.dtype).name,
+        jnp.dtype(k.dtype).name,
         jnp.dtype(v.dtype).name, bq, bk, causal, window, Nq, Nk, G,
         rope is not None, sparse, interpret)
     dk, dv = dkv_call(*dkv_sched, qp, gp, lsep, deltap, kp, vp, *rope_ops)

@@ -67,8 +67,8 @@ def _w_index(Ew: int):
 
 
 def _grouped_fwd_kernel(gid_ref, x_ref, w_ref, a_ref, b_ref, o_ref,
-                        acc_ref, h_ref, *, scale: float, n_k: int):
-    k = pl.program_id(2)
+                        acc_ref, h_ref, *, scale: float, n_k: int, pid=None):
+    k = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(k == 0)
     def _init():
@@ -89,8 +89,9 @@ def _grouped_fwd_kernel(gid_ref, x_ref, w_ref, a_ref, b_ref, o_ref,
 
 
 def _grouped_fwd_q_kernel(gid_ref, x_ref, q_ref, s_ref, a_ref, b_ref, o_ref,
-                          acc_ref, h_ref, *, scale: float, n_k: int):
-    k = pl.program_id(2)
+                          acc_ref, h_ref, *, scale: float, n_k: int,
+                          pid=None):
+    k = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(k == 0)
     def _init():
@@ -113,8 +114,8 @@ def _grouped_fwd_q_kernel(gid_ref, x_ref, q_ref, s_ref, a_ref, b_ref, o_ref,
 
 def _grouped_fwd_q4_kernel(gid_ref, x_ref, q4_ref, s_ref, a_ref, b_ref,
                            o_ref, acc_ref, h_ref, *, scale: float, n_k: int,
-                           method: str):
-    k = pl.program_id(2)
+                           method: str, pid=None):
+    k = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(k == 0)
     def _init():
@@ -253,8 +254,8 @@ def lora_grouped_q4(x, q4, s, a, b, gid, scale: float = 2.0, *,
 
 
 def _grouped_dx_kernel(gid_ref, g_ref, w_ref, dh_ref, a_ref, o_ref, acc_ref,
-                       *, n_n: int):
-    n = pl.program_id(2)
+                       *, n_n: int, pid=None):
+    n = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(n == 0)
     def _init():
@@ -274,8 +275,8 @@ def _grouped_dx_kernel(gid_ref, g_ref, w_ref, dh_ref, a_ref, o_ref, acc_ref,
 
 
 def _grouped_dx_q_kernel(gid_ref, g_ref, q_ref, s_ref, dh_ref, a_ref, o_ref,
-                         acc_ref, *, n_n: int):
-    n = pl.program_id(2)
+                         acc_ref, *, n_n: int, pid=None):
+    n = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(n == 0)
     def _init():
@@ -296,8 +297,8 @@ def _grouped_dx_q_kernel(gid_ref, g_ref, q_ref, s_ref, dh_ref, a_ref, o_ref,
 
 
 def _grouped_dx_q4_kernel(gid_ref, g_ref, q4_ref, s_ref, dh_ref, a_ref,
-                          o_ref, acc_ref, *, n_n: int, method: str):
-    n = pl.program_id(2)
+                          o_ref, acc_ref, *, n_n: int, method: str, pid=None):
+    n = pl.program_id(2) if pid is None else pid[2]
 
     @pl.when(n == 0)
     def _init():
@@ -444,8 +445,8 @@ def lora_grouped_dx_q4(g, q4, s, a, b, gid, scale: float = 2.0, *,
 
 
 def _grouped_dab_kernel(gid_ref, x_ref, g_ref, a_ref, b_ref, da_ref, db_ref,
-                        *, scale: float):
-    t = pl.program_id(0)
+                        *, scale: float, pid=None):
+    t = pl.program_id(0) if pid is None else pid[0]
     # first tile of a contiguous group run -> this (da, db) block is fresh
     first = (t == 0) | (gid_ref[t] != gid_ref[jnp.maximum(t - 1, 0)])
 
@@ -526,4 +527,266 @@ def lora_grouped_dab(x, g, a, b, gid, scale: float = 2.0, *, bm: int = 128,
     live = jnp.zeros((E,), bool).at[gid].set(True)
     da = jnp.where(live[:, None, None], da[:, :K], 0.0)
     db = jnp.where(live[:, None, None], db[:, :, :N], 0.0)
+    return da.astype(a.dtype), db.astype(b.dtype)
+
+
+# ---------------------------------------------------------------------------
+# experts routed inside the step: the tile schedule lives on the device
+# ---------------------------------------------------------------------------
+#
+# A MoE layer decides its routing inside the jitted step, so its row
+# layout cannot be a host-side schedule. The ``expert_*`` kernels take two
+# scalar-prefetched arrays: ``gid[t]``, the expert of row tile t, and
+# ``live[0]``, the number of tiles that hold routed rows (the buffer is
+# sized for the most rows routing can send, ``tiles ≥ live``). Tiles past
+# ``live`` skip the matmuls: their index maps repeat the last live step's
+# blocks, so no operand is fetched again, and the fwd/dx kernels write
+# zeros there (dab leaves its per-expert blocks alone).
+
+
+def _live_tile(on, t, live):
+    return jnp.where(on, t, jnp.maximum(live[0] - 1, 0))
+
+
+def _live_only(kern, n_in: int, zero_out: bool, n_grid: int):
+    """``kern`` (prefetch ref gid first) run on live tiles only; the grid
+    position is read here and handed in (``pid``)."""
+    def body(gid_ref, live_ref, *refs, **kw):
+        pid = tuple(pl.program_id(i) for i in range(n_grid))
+        live = pid[0] < live_ref[0]
+
+        @pl.when(live)
+        def _run():
+            kern(gid_ref, *refs, pid=pid, **kw)
+
+        if zero_out:
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                refs[n_in][...] = jnp.zeros_like(refs[n_in])
+    return body
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_fwd_call(Mp: int, Kp: int, Np: int, E: int, r: int,
+                     dtype_name: str, scale: float, bm: int, bn: int,
+                     bk: int, interpret: bool, quant: str):
+    n_k, n_n = Kp // bk, Np // bn
+    packed = quant in ("int4", "nf4")
+    wblk = (1, bk // 2, bn) if packed else (1, bk, bn)
+
+    def step(t, j, k, live):
+        """(tile, n block, k block) a step reads: past the routed rows,
+        those of the last live step."""
+        on = t < live[0]
+        return (_live_tile(on, t, live), jnp.where(on, j, n_n - 1),
+                jnp.where(on, k, n_k - 1))
+
+    def xmap(t, j, k, gid, live):
+        tt, _, kk = step(t, j, k, live)
+        return tt, kk
+
+    def wmap(t, j, k, gid, live):
+        tt, jj, kk = step(t, j, k, live)
+        return gid[tt], kk, jj
+
+    def smap(t, j, k, gid, live):
+        tt, jj, _ = step(t, j, k, live)
+        return gid[tt], 0, jj
+
+    def amap(t, j, k, gid, live):
+        tt, _, kk = step(t, j, k, live)
+        return gid[tt], kk, 0
+
+    in_specs = [pl.BlockSpec((bm, bk), xmap), pl.BlockSpec(wblk, wmap)]
+    if quant != "none":
+        in_specs.append(pl.BlockSpec((1, 1, bn), smap))
+    in_specs += [pl.BlockSpec((1, bk, r), amap),
+                 pl.BlockSpec((1, r, bn), smap)]
+    if packed:
+        kern = functools.partial(_grouped_fwd_q4_kernel, method=quant)
+    elif quant == "int8":
+        kern = _grouped_fwd_q_kernel
+    else:
+        kern = _grouped_fwd_kernel
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Mp // bm, n_n, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda t, j, k, gid, live: (t, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bm, r), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_live_only(kern, len(in_specs), True, 3),
+                          scale=scale, n_k=n_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.dtype(dtype_name)),
+        name="expert_fwd",
+        interpret=interpret,
+    )
+
+
+def _pad_base(w, s, quant: str, bk: int, bn: int):
+    """The frozen stack padded to the (K, N) block grid: dense or int8
+    [E, K, N], packed 4-bit [E, ceil(K/2), N]; scale rows [E, 1, N]."""
+    wp = pad_dim(pad_dim(w, bk // 2 if quant in ("int4", "nf4") else bk, 1),
+                 bn, 2)
+    if s is None:
+        return [wp]
+    return [wp, pad_dim(s.astype(jnp.float32), bn, 2)]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "quant", "bm", "bn",
+                                             "bk", "interpret"))
+def expert_fwd(x, w, s, a, b, gid, live, scale: float = 2.0, *,
+               quant: str = "none", bm: int = 256, bn: int = 512,
+               bk: int = 2048, interpret: bool = False):
+    """x:[Mp,K] (rows packed to bm-tiles of one expert each), w:[E,K,N]
+    (or int8 / packed 4-bit with scale rows ``s``), a:[E,K,r], b:[E,r,N],
+    gid:int32[Mp//bm], live:int32[1] -> [Mp,N], zero past the live tiles."""
+    Mp, K = x.shape
+    E, _, r = a.shape
+    N = b.shape[2]
+    bn, bk = block_for(N, bn), block_for(K, bk)
+    xp = pad_dim(x, bk, 1)
+    ap = pad_dim(a, bk, 1)
+    bp = pad_dim(b, bn, 2)
+    base = _pad_base(w, s, quant, bk, bn)
+    Kp, Np = xp.shape[1], bp.shape[2]
+    out = _expert_fwd_call(Mp, Kp, Np, E, r, jnp.dtype(x.dtype).name,
+                           float(scale), bm, bn, bk, interpret, quant)(
+        gid, live, xp, *base, ap, bp)
+    return out[:, :N]
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_dx_call(Mp: int, Kp: int, Np: int, E: int, r: int,
+                    dtype_name: str, bm: int, bk: int, bn: int,
+                    interpret: bool, quant: str):
+    n_j, n_n = Kp // bk, Np // bn
+    packed = quant in ("int4", "nf4")
+    wblk = (1, bk // 2, bn) if packed else (1, bk, bn)
+
+    def step(t, j, n, live):
+        on = t < live[0]
+        return (_live_tile(on, t, live), jnp.where(on, j, n_j - 1),
+                jnp.where(on, n, n_n - 1))
+
+    def gmap(t, j, n, gid, live):
+        tt, _, nn = step(t, j, n, live)
+        return tt, nn
+
+    def wmap(t, j, n, gid, live):
+        tt, jj, nn = step(t, j, n, live)
+        return gid[tt], jj, nn
+
+    def smap(t, j, n, gid, live):
+        tt, _, nn = step(t, j, n, live)
+        return gid[tt], 0, nn
+
+    def dhmap(t, j, n, gid, live):
+        tt, _, _ = step(t, j, n, live)
+        return tt, 0
+
+    def amap(t, j, n, gid, live):
+        tt, jj, _ = step(t, j, n, live)
+        return gid[tt], jj, 0
+
+    in_specs = [pl.BlockSpec((bm, bn), gmap), pl.BlockSpec(wblk, wmap)]
+    if quant != "none":
+        in_specs.append(pl.BlockSpec((1, 1, bn), smap))
+    in_specs += [pl.BlockSpec((bm, r), dhmap),
+                 pl.BlockSpec((1, bk, r), amap)]
+    if packed:
+        kern = functools.partial(_grouped_dx_q4_kernel, method=quant)
+    elif quant == "int8":
+        kern = _grouped_dx_q_kernel
+    else:
+        kern = _grouped_dx_kernel
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Mp // bm, n_j, n_n),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bk), lambda t, j, n, gid, live: (t, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_live_only(kern, len(in_specs), True, 3), n_n=n_n),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Mp, Kp), jnp.dtype(dtype_name)),
+        name="expert_dx",
+        interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "quant", "bm", "bk",
+                                             "bn", "interpret"))
+def expert_dx(g, w, s, a, b, gid, live, scale: float = 2.0, *,
+              quant: str = "none", bm: int = 256, bk: int = 512,
+              bn: int = 2048, interpret: bool = False):
+    """dx = (s·g)@B[g]ᵀ@A[g]ᵀ + g@W0[g]ᵀ on live tiles. g:[Mp,N] ->
+    dx:[Mp,K], zero past the live tiles."""
+    Mp, N = g.shape
+    E, K, r = a.shape
+    bk, bn = block_for(K, bk), block_for(N, bn)
+    dh = _grouped_dh(g, b, gid, scale, bm)
+    gp = pad_dim(g, bn, 1)
+    ap = pad_dim(a, bk, 1)
+    base = _pad_base(w, s, quant, bk, bn)
+    Np, Kp = gp.shape[1], ap.shape[1]
+    out = _expert_dx_call(Mp, Kp, Np, E, r, jnp.dtype(g.dtype).name, bm,
+                          bk, bn, interpret, quant)(gid, live, gp, *base,
+                                                    dh, ap)
+    return out[:, :K]
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_dab_call(Mp: int, Kp: int, Np: int, E: int, r: int,
+                     scale: float, bm: int, interpret: bool):
+    def rows(t, gid, live):
+        return _live_tile(t < live[0], t, live), 0
+
+    def group(t, gid, live):
+        return gid[t], 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Mp // bm,),
+        in_specs=[pl.BlockSpec((bm, Kp), rows), pl.BlockSpec((bm, Np), rows),
+                  pl.BlockSpec((1, Kp, r), group),
+                  pl.BlockSpec((1, r, Np), group)],
+        out_specs=[pl.BlockSpec((1, Kp, r), group),
+                   pl.BlockSpec((1, r, Np), group)],
+        scratch_shapes=[],
+    )
+    return pl.pallas_call(
+        functools.partial(_live_only(_grouped_dab_kernel, 4, False, 1),
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((E, Kp, r), jnp.float32),
+                   jax.ShapeDtypeStruct((E, r, Np), jnp.float32)],
+        name="expert_dab",
+        interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "interpret"))
+def expert_dab(x, g, a, b, gid, live, scale: float = 2.0, *, bm: int = 256,
+               interpret: bool = False):
+    """(dA, dB) per expert over the live tiles, one pass over x/g; experts
+    with no live tile get zeros. Tiles of one expert are contiguous."""
+    Mp, K = x.shape
+    N = g.shape[1]
+    E, _, r = a.shape
+    xp = pad_dim(x, 128, 1)
+    gp = pad_dim(g, 128, 1)
+    ap = pad_dim(a, 128, 1)
+    bp = pad_dim(b, 128, 2)
+    Kp, Np = xp.shape[1], gp.shape[1]
+    da, db = _expert_dab_call(Mp, Kp, Np, E, r, float(scale), bm,
+                              interpret)(gid, live, xp, gp, ap, bp)
+    on = jnp.arange(gid.shape[0]) < live[0]
+    held = jnp.zeros((E,), bool).at[gid].max(on)
+    da = jnp.where(held[:, None, None], da[:, :K], 0.0)
+    db = jnp.where(held[:, None, None], db[:, :, :N], 0.0)
     return da.astype(a.dtype), db.astype(b.dtype)

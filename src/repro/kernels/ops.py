@@ -47,7 +47,8 @@ from repro.telemetry.metrics import CounterGroup
 #: counted at trace time: once per call site each time a program is traced,
 #: not per execution. Module-level for the reason ``autotune.COUNTERS`` is;
 #: an enabled Telemetry adopts the group.
-FALLBACKS = CounterGroup("kernels.fallback", ("lora_linear", "sdpa"))
+FALLBACKS = CounterGroup("kernels.fallback",
+                         ("lora_linear", "sdpa", "expert_linear"))
 
 # Below this many query rows the dense structured sdpa beats the kernel's
 # padding + grid overhead (and is easier to cross-check).
@@ -450,6 +451,138 @@ def lora_grouped_decode(x, w0, a, b, tile_gid, bias=None, scale: float = 2.0,
 
 
 # ---------------------------------------------------------------------------
+# Expert LoRA linear on a device-side tile schedule: the MoE layer routes
+# inside the step, so its rows are packed on the device and the expert of
+# each row tile (tile_gid) and the count of tiles holding routed rows
+# (n_live) are traced arrays. Tiles past n_live are skipped by the kernels.
+# ---------------------------------------------------------------------------
+
+
+def _expert_base(w0):
+    """(frozen operand, scale rows, quant name) of an expert stack."""
+    if quant.is_packed(w0):
+        return w0["q4"], w0["scale"], quant.packed_method(w0)
+    if quant.is_quantized(w0):
+        return w0["q"], w0["scale"], "int8"
+    return w0, None, "none"
+
+
+def _expert_blocks(K: int, N: int):
+    """Blocks of the expert kernels: whole rows of K (up to 2048) in the
+    forward and of N in dx, so a tile past the routed rows costs one grid
+    step or a few."""
+    fwd = {"bn": 512, "bk": 2048 if K <= 2048 else 512}
+    dx = {"bk": 512, "bn": 2048 if N <= 2048 else 512}
+    return fwd, dx
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _expert_core(x, w, s, a, b, gid, live, scale: float, bm: int,
+                 quant_name: str, interpret: bool):
+    fwd, _ = _expert_blocks(x.shape[1], b.shape[2])
+    return _lg.expert_fwd(x, w, s, a, b, gid, live, scale, quant=quant_name,
+                          bm=bm, interpret=interpret, **fwd)
+
+
+def _expert_fwd(x, w, s, a, b, gid, live, scale, bm, quant_name, interpret):
+    y = _expert_core(x, w, s, a, b, gid, live, scale, bm, quant_name,
+                     interpret)
+    return y, (x, w, s, a, b, gid, live)
+
+
+def _expert_bwd(scale, bm, quant_name, interpret, res, g):
+    x, w, s, a, b, gid, live = res
+    g = g.astype(x.dtype)
+    _, dxb = _expert_blocks(x.shape[1], b.shape[2])
+    dx = _lg.expert_dx(g, w, s, a, b, gid, live, scale, quant=quant_name,
+                       bm=bm, interpret=interpret, **dxb)
+    # h = x·A recomputed tile-wise in VMEM (paper §4.1), never stored
+    da, db = _lg.expert_dab(x, g, a, b, gid, live, scale, bm=bm,
+                            interpret=interpret)
+    dw = structured._zero_cot(w) if quant_name != "none" \
+        else jnp.zeros_like(w)
+    ds = None if s is None else jnp.zeros_like(s)
+    return (dx, dw, ds, da, db, structured._zero_cot(gid),
+            structured._zero_cot(live))
+
+
+_expert_core.defvjp(_expert_fwd, _expert_bwd)
+
+
+def _tile_group_sizes(tile_gid, n_live, n_groups: int, bm: int):
+    """Rows of each group in the packed layout (whole live tiles)."""
+    on = jnp.arange(tile_gid.shape[0]) < n_live
+    return jnp.zeros((n_groups,), jnp.int32).at[tile_gid].add(
+        jnp.where(on, bm, 0))
+
+
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ragged_lora(x, w0, a, b, sizes, scale: float):
+    """Structured (MeSP) ragged LoRA linear: only x is kept; h = x·A is
+    recomputed in the backward."""
+    return _ragged(x, w0, sizes) + scale * _ragged(_ragged(x, a, sizes), b,
+                                                   sizes)
+
+
+def _ragged_lora_fwd(x, w0, a, b, sizes, scale):
+    return _ragged_lora(x, w0, a, b, sizes, scale), (x, w0, a, b, sizes)
+
+
+def _ragged_lora_bwd(scale, res, g):
+    x, w0, a, b, sizes = res
+    g = g.astype(x.dtype)
+    h, h_vjp = jax.vjp(lambda x_, a_: _ragged(x_, a_, sizes), x, a)
+    _, b_vjp = jax.vjp(lambda h_, b_: _ragged(h_, b_, sizes), h, b)
+    dh, db = b_vjp(scale * g)
+    dx_lora, da = h_vjp(dh)
+    _, w_vjp = jax.vjp(lambda x_: _ragged(x_, w0, sizes), x)
+    (dx,) = w_vjp(g)
+    return (dx + dx_lora, jnp.zeros_like(w0), da, db,
+            structured._zero_cot(sizes))
+
+
+_ragged_lora.defvjp(_ragged_lora_fwd, _ragged_lora_bwd)
+
+
+def lora_expert_linear(x, w0, a, b, tile_gid, n_live, scale: float = 2.0, *,
+                       bm: int, policy=None, interpret=None):
+    """Expert linear over rows packed by expert: x:[Mp,K] with every
+    ``bm``-row tile one expert's (``tile_gid`` int32 [Mp//bm], traced), the
+    first ``n_live`` tiles holding routed rows; w0:[E,K,N] dense or
+    quantized, a:[E,K,r], b:[E,r,N] (None: no LoRA). Rows past the live
+    tiles give zeros. Differentiable in (x, a, b).
+
+    The pallas backend runs the ``expert_*`` kernels; the other backends
+    (and a target without LoRA on the pallas backend, counted in
+    ``FALLBACKS["expert_linear"]``) run ``jax.lax.ragged_dot`` over the
+    tiles' group sizes — autodiff (h stored) for ``plain``/``store_h``,
+    h recomputed for ``structured``."""
+    backend = policy.backend if policy is not None else "structured"
+    if backend == "pallas" and a is not None:
+        w, s, qn = _expert_base(w0)
+        live = jnp.reshape(jnp.asarray(n_live, jnp.int32), (1,))
+        return _expert_core(x, w, s, a, b, jnp.asarray(tile_gid, jnp.int32),
+                            live, scale, bm, qn,
+                            _resolve_interpret(policy, interpret))
+    if backend == "pallas":
+        FALLBACKS["expert_linear"] += 1
+    w = quant.maybe_dequant(w0, x.dtype)
+    sizes = _tile_group_sizes(tile_gid, n_live, w.shape[0], bm)
+    if a is None:
+        return _ragged(x, w, sizes)
+    if backend in ("plain", "store_h"):
+        return _ragged(x, w, sizes) + scale * _ragged(_ragged(x, a, sizes),
+                                                      b, sizes)
+    return _ragged_lora(x, w, a, b, sizes, scale)
+
+
+# ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
 
@@ -470,7 +603,7 @@ def _rn_fwd(x, w, eps, interpret):
 def _rn_bwd(eps, interpret, res, g):
     x, w = res
     x2 = _flat(x)
-    blk = autotune.choose_blocks("rmsnorm", x.dtype, M=x2.shape[0],
+    blk = autotune.choose_blocks("rmsnorm_bwd", x.dtype, M=x2.shape[0],
                                  d=x2.shape[1])
     dx, dw = _rn.rmsnorm_bwd(x2, w, _flat(g), eps, interpret=interpret,
                              **blk)
@@ -503,7 +636,8 @@ def _attn_blocks(Nq, Nk, D, dtype, causal, window):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     interpret: bool = False, rope=None):
-    """q: [B,H,N,D]; k/v: [B,Hkv,Nk,D] -> [B,H,N,D]. Differentiable.
+    """q: [B,H,N,D]; k: [B,Hkv,Nk,D]; v: [B,Hkv,Nk,Dv] -> [B,H,N,Dv]
+    (Dv may differ from the q·k width D). Differentiable.
     ``rope=(cos, sin)`` ([N, D/2] f32) fuses the q/k rotation into the
     kernels (tables are treated as constants — zero cotangent)."""
     out, _ = _flash_fwd_impl(q, k, v, rope, causal, window, interpret)
@@ -516,10 +650,11 @@ def _flash_fwd_impl(q, k, v, rope, causal, window, interpret):
     blk = _attn_blocks(Nq, Nk, D, q.dtype, causal, window)
     out, lse = _fa.flash_attention_fwd(
         q.reshape(B * H, Nq, D), k.reshape(B * Hkv, Nk, D),
-        v.reshape(B * Hkv, Nk, D), rope, causal=causal, window=window,
-        q_per_kv=H // Hkv, interpret=interpret, return_lse=True,
+        v.reshape(B * Hkv, Nk, v.shape[-1]), rope, causal=causal,
+        window=window, q_per_kv=H // Hkv, interpret=interpret,
+        return_lse=True,
         bq=blk["bq"], bk=blk["bk"])
-    return out.reshape(B, H, Nq, D), lse
+    return out.reshape(B, H, Nq, v.shape[-1]), lse
 
 
 def _flash_vjp_fwd(q, k, v, causal, window, interpret, rope):
@@ -531,18 +666,18 @@ def _flash_vjp_fwd(q, k, v, causal, window, interpret, rope):
 def _flash_vjp_bwd(causal, window, interpret, res, g):
     q, k, v, rope, out, lse = res
     B, H, Nq, D = q.shape
-    Hkv, Nk = k.shape[1], k.shape[2]
+    Hkv, Nk, Dv = k.shape[1], k.shape[2], v.shape[-1]
     blk = _attn_blocks(Nq, Nk, D, q.dtype, causal, window)
     dq, dk, dv = _fa.flash_attention_bwd(
         q.reshape(B * H, Nq, D), k.reshape(B * Hkv, Nk, D),
-        v.reshape(B * Hkv, Nk, D), out.reshape(B * H, Nq, D), lse,
-        g.reshape(B * H, Nq, D), rope, causal=causal, window=window,
+        v.reshape(B * Hkv, Nk, Dv), out.reshape(B * H, Nq, Dv), lse,
+        g.reshape(B * H, Nq, Dv), rope, causal=causal, window=window,
         q_per_kv=H // Hkv, interpret=interpret,
         bq=blk["bq"], bk=blk["bk"])
     d_rope = None if rope is None else (jnp.zeros_like(rope[0]),
                                         jnp.zeros_like(rope[1]))
     return (dq.reshape(B, H, Nq, D), dk.reshape(B, Hkv, Nk, D),
-            dv.reshape(B, Hkv, Nk, D), d_rope)
+            dv.reshape(B, Hkv, Nk, Dv), d_rope)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

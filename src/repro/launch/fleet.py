@@ -181,15 +181,17 @@ def task_train(payload: dict) -> dict:
     tel.emit(tele.RunEvent(phase="start", engine=spec.engine,
                            quantize=spec.quantize, arch=spec.arch,
                            steps=int(payload.get("steps", spec.steps))))
-    losses, times = [], []
+    losses, times, counters = [], [], {}
     try:
         for step in range(int(payload.get("steps", spec.steps))):
             batch = synth_batch(tr.cfg.vocab, spec.batch, spec.seq,
                                 spec.seed, step)
             t0 = time.perf_counter()
             with tel.span("fleet/step"):
-                params, opt_state, loss = jax.block_until_ready(
+                params, opt_state, loss, *aux = jax.block_until_ready(
                     tr.step_fn(params, opt_state, batch))
+            for k, v in (aux[0] if aux else {}).items():
+                counters[k] = counters.get(k, 0) + int(v)
             dt = time.perf_counter() - t0
             times.append(dt)
             losses.append(float(loss))
@@ -210,7 +212,8 @@ def task_train(payload: dict) -> dict:
     steady = times[WARMUP_STEPS:] or times
     result = {"losses": losses, "step_times_s": times,
               "step_time_s": float(np.median(steady)),
-              "devices": jax.device_count(), "mesh": _mesh_axes(tr.mesh)}
+              "devices": jax.device_count(), "mesh": _mesh_axes(tr.mesh),
+              "counters": counters}
     if tel.enabled and tel.out_dir:
         result["telemetry_shard"] = os.path.join(
             tel.out_dir, f"worker_{tel.worker}.jsonl")
@@ -299,7 +302,8 @@ def task_elastic(payload: dict) -> dict:
     gen = batches()
     losses_a = []
     for _ in range(sum(phases)):
-        params_a, opt_a, loss = TrainerRef.step_fn(params_a, opt_a, next(gen))
+        params_a, opt_a, loss, *_ = TrainerRef.step_fn(params_a, opt_a,
+                                                       next(gen))
         losses_a.append(float(loss))
 
     # --- reshard_tree round trip is placement-only (bit-exact)
@@ -326,7 +330,7 @@ def task_elastic(payload: dict) -> dict:
             params_b, opt_b = tr.resize(devs, params=params_b,
                                         opt_state=opt_b)
         for _ in range(n):
-            params_b, opt_b, loss = tr.step_fn(params_b, opt_b, next(gen))
+            params_b, opt_b, loss, *_ = tr.step_fn(params_b, opt_b, next(gen))
             losses_b.append(float(loss))
 
     # --- C: checkpoint-restore path (host round trip + fresh Trainer)
@@ -343,7 +347,7 @@ def task_elastic(payload: dict) -> dict:
             state = trc.init_state()
         params_c, opt_c = trc.shard_state(*state)
         for _ in range(n):
-            params_c, opt_c, loss = trc.step_fn(params_c, opt_c, next(gen))
+            params_c, opt_c, loss, *_ = trc.step_fn(params_c, opt_c, next(gen))
             losses_c.append(float(loss))
         state = (to_host(params_c), to_host(opt_c))
 
@@ -398,7 +402,7 @@ def task_ladder(payload: dict) -> dict:
         live = tr.live_spec
         batch = synth_batch(tr.cfg.vocab, live.batch, live.seq,
                             live.seed, 0)
-        _, _, loss = tr.step_fn(params, opt_state, batch)
+        _, _, loss, *_ = tr.step_fn(params, opt_state, batch)
         rungs.append({"rung": rung, "built": True,
                       "loss": float(loss),
                       "finite": bool(np.isfinite(float(loss))),

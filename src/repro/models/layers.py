@@ -298,6 +298,71 @@ def attention(p, x, cfg: ArchConfig, *, window: int = 0, causal: bool = True,
     return lin(p["o"], out), new_cache
 
 
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2/V3) — training path
+# ---------------------------------------------------------------------------
+
+
+def mla_params(key, cfg: ArchConfig, *, lora: bool = True):
+    """q [d → H·(nope+rope)], kv_a [d → rank+rope] with an RMSNorm over the
+    rank-wide latent, kv_b [rank → H·(nope+v)], o [H·v → d]."""
+    m = cfg.mla
+    ks = _split(key, 4)
+    tg = cfg.lora.targets
+    H, d = cfg.n_heads, cfg.d_model
+    return {
+        "q": linear_params(ks[0], d, H * m.qk_head_dim, cfg,
+                           lora=lora and "q" in tg),
+        "kv_a": linear_params(ks[1], d, m.kv_lora_rank + m.qk_rope_head_dim,
+                              cfg, lora=lora and "kv_a" in tg),
+        "kv_norm": jnp.ones((m.kv_lora_rank,), jnp.dtype(cfg.dtype)),
+        "kv_b": linear_params(ks[2], m.kv_lora_rank,
+                              H * (m.qk_nope_head_dim + m.v_head_dim), cfg,
+                              lora=lora and "kv_b" in tg),
+        "o": linear_params(ks[3], H * m.v_head_dim, d, cfg,
+                           lora=lora and "o" in tg),
+    }
+
+
+def mla_attention(p, x, cfg: ArchConfig, *, policy: ExecutionPolicy =
+                  STRUCTURED, shard=None, cache=None, **_):
+    """Causal latent attention over x [B, N, d], training and prefill only
+    (no KV cache). Rope (rotate-half, ``cfg.rope_theta``) turns only the
+    rope channels of q and of the shared key; the softmax scale is
+    1/√(nope + rope)."""
+    if cache is not None:
+        raise NotImplementedError("latent attention has no decode cache")
+    m = cfg.mla
+    if m.q_lora_rank is not None:
+        raise NotImplementedError("MLA with a q_lora_rank is not modelled")
+    B, N, _ = x.shape
+    H, nope, rd, dv = (cfg.n_heads, m.qk_nope_head_dim, m.qk_rope_head_dim,
+                       m.v_head_dim)
+    lin = functools.partial(apply_linear, cfg=cfg, policy=policy)
+    with jax.named_scope("mla"):
+        q = lin(p["q"], x).reshape(B, N, H, nope + rd)
+        kv = lin(p["kv_a"], x)
+        c = norm(p["kv_norm"], kv[..., :m.kv_lora_rank], cfg, policy=policy)
+        kvb = lin(p["kv_b"], c).reshape(B, N, H, nope + dv)
+        pos = jnp.arange(N)
+        q_pe = rope(q[..., nope:], pos, cfg.rope_theta)
+        k_pe = rope(kv[..., None, m.kv_lora_rank:], pos, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_pe], -1)
+        k = jnp.concatenate(
+            [kvb[..., :nope], jnp.broadcast_to(k_pe, (B, N, H, rd))], -1)
+        q = _head_constrain(q.transpose(0, 2, 1, 3), shard)   # [B,H,N,D]
+        k = _head_constrain(k.transpose(0, 2, 1, 3), shard)
+        v = _head_constrain(kvb[..., nope:].transpose(0, 2, 1, 3), shard)
+        if policy.backend == "plain":
+            out = structured._sdpa_ref(q, k, v, 0, True, 0, None)
+        elif policy.backend == "pallas":
+            out = kops.sdpa(q, k, v, causal=True, policy=policy)
+        else:
+            out = structured.sdpa(q, k, v, 0, True)
+        out = out.transpose(0, 2, 1, 3).reshape(B, N, H * dv)
+        return lin(p["o"], out), None
+
+
 def _cache_write(c, u, ln):
     """Write ``u`` into cache ``c`` ([B,Hkv,S,D]) at slot offset ``ln`` —
     a scalar (whole batch at one position, training/simple decode) or a

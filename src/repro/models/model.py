@@ -38,9 +38,14 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 
 
+def _attention(cfg):
+    """The block's attention: latent (MLA) where the config has one."""
+    return layers.mla_attention if cfg.mla is not None else layers.attention
+
+
 def dense_block(bp, x, cfg, *, window=0, policy: ExecutionPolicy = STRUCTURED,
                 cache=None, pos=0, shard=None, adapter_tiles=None):
-    h, new_cache = layers.attention(
+    h, new_cache = _attention(cfg)(
         bp["attn"], layers.norm(bp["ln1"], x, cfg, policy=policy), cfg,
         window=window, cache=cache, pos=pos, policy=policy, shard=shard,
         adapter_tiles=adapter_tiles)
@@ -53,21 +58,24 @@ def dense_block(bp, x, cfg, *, window=0, policy: ExecutionPolicy = STRUCTURED,
 
 def moe_block(bp, x, cfg, *, window=0, policy: ExecutionPolicy = STRUCTURED,
               cache=None, pos=0, shard=None, adapter_tiles=None):
-    h, new_cache = layers.attention(
+    """Returns (x, new cache, the expert layer's counters)."""
+    h, new_cache = _attention(cfg)(
         bp["attn"], layers.norm(bp["ln1"], x, cfg, policy=policy), cfg,
         window=window, cache=cache, pos=pos, policy=policy, shard=shard,
         adapter_tiles=adapter_tiles)
     x = x + h
-    x = x + moe_lib.moe_mlp(bp["moe"],
-                            layers.norm(bp["ln2"], x, cfg, policy=policy),
-                            cfg, policy=policy, shard=shard)
-    return x, new_cache
+    y, stats = moe_lib.moe_mlp(bp["moe"],
+                               layers.norm(bp["ln2"], x, cfg, policy=policy),
+                               cfg, policy=policy, shard=shard)
+    return x + y, new_cache, stats
 
 
 def _block_params(key, cfg: ArchConfig, kind: str):
     ks = jax.random.split(key, 3)
     dtype = jnp.dtype(cfg.dtype)
     d = cfg.d_model
+    attn_params = (layers.mla_params if cfg.mla is not None
+                   else layers.attention_params)
     if kind == "dense":
         return {"ln1": jnp.ones((d,), dtype),
                 "attn": layers.attention_params(ks[0], cfg),
@@ -75,16 +83,15 @@ def _block_params(key, cfg: ArchConfig, kind: str):
                 "mlp": layers.mlp_params(ks[1], cfg)}
     if kind == "moe":
         return {"ln1": jnp.ones((d,), dtype),
-                "attn": layers.attention_params(ks[0], cfg),
+                "attn": attn_params(ks[0], cfg),
                 "ln2": jnp.ones((d,), dtype),
                 "moe": moe_lib.moe_params(ks[1], cfg)}
-    if kind == "moe_dense0":  # deepseek layer 0: dense FFN of matched width
-        m = cfg.moe
+    if kind == "moe_dense0":  # deepseek layer 0: a dense FFN
         return {"ln1": jnp.ones((d,), dtype),
-                "attn": layers.attention_params(ks[0], cfg),
+                "attn": attn_params(ks[0], cfg),
                 "ln2": jnp.ones((d,), dtype),
-                "mlp": layers.mlp_params(
-                    ks[1], cfg, d_ff=m.d_expert * (m.top_k + m.n_shared))}
+                "mlp": layers.mlp_params(ks[1], cfg,
+                                         d_ff=cfg.moe.resolved_d_dense)}
     if kind == "rwkv":
         return rwkv6.rwkv_block_params(key, cfg)
     if kind == "recurrent":
@@ -234,6 +241,19 @@ def forward(params, cfg: ArchConfig, tokens: Array, *,
             frontend_embeds: Optional[Array] = None,
             enc_frames: Optional[Array] = None) -> Array:
     """Full-sequence forward -> logits [B, N(+frontend), vocab] (fp32)."""
+    return forward_and_aux(params, cfg, tokens, policy=policy,
+                           frontend_embeds=frontend_embeds,
+                           enc_frames=enc_frames)[0]
+
+
+def forward_and_aux(params, cfg: ArchConfig, tokens: Array, *,
+                    policy: ExecutionPolicy = STRUCTURED,
+                    frontend_embeds: Optional[Array] = None,
+                    enc_frames: Optional[Array] = None):
+    """(logits, aux): aux holds the ``moe`` family's expert counters summed
+    over its layers (``moe_lib.STATS``, int32 scalars), and is empty for
+    every other family."""
+    aux = {}
     act_spec = policy.act_spec
     remat = policy.remat
     x = layers.embed(params["embed"], tokens, cfg)
@@ -269,10 +289,16 @@ def forward(params, cfg: ArchConfig, tokens: Array, *,
             x, _ = dense_block(params["block0"], x, cfg, policy=policy,
                                shard=shard)
 
-        def body(x, bp):
-            return moe_block(bp, x, cfg, policy=policy, shard=shard)[0]
+        def body(carry, bp):
+            x, stats = carry
+            x, _, s = moe_block(bp, x, cfg, policy=policy, shard=shard)
+            x = _constrain(x, act_spec)
+            return (x, {k: stats[k] + s[k] for k in stats}), None
 
-        x = _scan_ckpt(body, x, params["blocks"], act_spec, remat)
+        f = jax.checkpoint(body) if remat else body
+        zero = {k: jnp.int32(0) for k in moe_lib.STATS}
+        (x, aux), _ = jax.lax.scan(f, (_constrain(x, act_spec), zero),
+                                   params["blocks"])
     elif fam == "ssm":
         def body(x, bp):
             return rwkv6.rwkv_block(bp, x, cfg, policy=policy)[0]
@@ -329,22 +355,35 @@ def forward(params, cfg: ArchConfig, tokens: Array, *,
         raise ValueError(fam)
 
     x = layers.norm(params["final_norm"], x, cfg, policy=policy)
-    return layers.unembed(params["embed"], x, cfg)
+    return layers.unembed(params["embed"], x, cfg), aux
+
+
+def aux_names(cfg: ArchConfig) -> tuple:
+    """Names of the counters :func:`loss_and_aux` returns beside the loss:
+    the ``moe`` family's expert counters, none for every other family."""
+    return moe_lib.STATS if cfg.family == "moe" else ()
 
 
 def loss_fn(params, cfg: ArchConfig, batch: dict, *,
             policy: ExecutionPolicy = STRUCTURED) -> Array:
     """Mean next-token CE. batch: tokens/labels [B,N] (+frontend/frames)."""
-    logits = forward(params, cfg, batch["tokens"], policy=policy,
-                     frontend_embeds=batch.get("frontend_embeds"),
-                     enc_frames=batch.get("enc_frames"))
+    return loss_and_aux(params, cfg, batch, policy=policy)[0]
+
+
+def loss_and_aux(params, cfg: ArchConfig, batch: dict, *,
+                 policy: ExecutionPolicy = STRUCTURED):
+    """(mean next-token CE, aux) — aux as :func:`forward_and_aux` gives."""
+    logits, aux = forward_and_aux(
+        params, cfg, batch["tokens"], policy=policy,
+        frontend_embeds=batch.get("frontend_embeds"),
+        enc_frames=batch.get("enc_frames"))
     labels = batch["labels"]
     if cfg.frontend_tokens and batch.get("frontend_embeds") is not None:
         # frontend prefix carries no labels
         pad = jnp.full(labels.shape[:1] + (batch["frontend_embeds"].shape[1],),
                        -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
-    return structured.softmax_xent(logits, labels)
+    return structured.softmax_xent(logits, labels), aux
 
 
 # ---------------------------------------------------------------------------
@@ -466,7 +505,7 @@ def decode_step(params, cfg: ArchConfig, cache, tokens: Array, *,
             def body(x, bs):
                 bp, lc = bs
                 x, nc = blk(bp, x, cfg, cache=lc, pos=lc["len"],
-                            policy=policy, adapter_tiles=adapter_tiles)
+                            policy=policy, adapter_tiles=adapter_tiles)[:2]
                 return x, nc
 
             x, nc = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))

@@ -131,7 +131,7 @@ class RestartRequired(RuntimeError):
 class ResilientLoop:
     """Supervised training-step driver.
 
-    step_fn(params, opt_state, batch) -> (params, opt_state, loss)
+    step_fn(params, opt_state, batch) -> (params, opt_state, loss[, aux])
     init_state() -> (params, opt_state)
 
     Pluggable hooks (all optional) let the Trainer facade wire in the full
@@ -164,6 +164,10 @@ class ResilientLoop:
       ``train`` (``steps`` completed, ``host_syncs``: device→host reads, one
       a step — the loss, with the guard's squared update norm when it
       tracks one); registered with an enabled telemetry.
+    * ``aux_counters`` — :class:`~repro.telemetry.metrics.CounterGroup`
+      that a step's fourth output (a dict of integer counters, such as the
+      expert layers' ``moe.*``) is added to when the step commits; it is
+      read in the loss's sync, never in one of its own.
     * ``memwatch`` — :class:`repro.telemetry.MemoryWatermark`; sampled after
       every successful step.
     * ``pressure`` — :class:`repro.runtime.degrade.WatermarkTrigger`; fed
@@ -191,7 +195,8 @@ class ResilientLoop:
                  telemetry=None,
                  memwatch=None,
                  pressure=None,
-                 train_counters: Optional[CounterGroup] = None):
+                 train_counters: Optional[CounterGroup] = None,
+                 aux_counters: Optional[CounterGroup] = None):
         self.step_fn = step_fn
         self.init_state = init_state
         self.batch_iter = batch_iter
@@ -215,8 +220,11 @@ class ResilientLoop:
         self.train_counters = (train_counters if train_counters is not None
                                else CounterGroup("train",
                                                  ("steps", "host_syncs")))
+        self.aux_counters = aux_counters
         if telemetry.enabled:
             telemetry.registry.register_group(self.train_counters)
+            if aux_counters is not None:
+                telemetry.registry.register_group(aux_counters)
         self._steps = self.train_counters.counter("steps")
         self._syncs = self.train_counters.counter("host_syncs")
         self.memwatch = memwatch
@@ -420,7 +428,7 @@ class ResilientLoop:
             with tel.span("train/data"):
                 batch = next(self.batch_iter)
             with tel.span("train/dispatch"):
-                new_params, new_opt, loss = self.step_fn(
+                new_params, new_opt, loss, *aux = self.step_fn(
                     self.params, self.opt_state, batch)
                 # queued behind the step, read with the loss below
                 sq_norm = (update_sq_norm(self.params, new_params)
@@ -428,8 +436,8 @@ class ResilientLoop:
             if self.injector is not None:
                 loss = self.injector.after_step(self.step, loss)
             with tel.span("train/loss_sync"):
-                if track_norm:
-                    loss, sq_norm = jax.device_get((loss, sq_norm))
+                if track_norm or aux:
+                    loss, sq_norm, aux = jax.device_get((loss, sq_norm, aux))
                 lossf = float(loss)
             self._syncs.inc()
         except (KeyboardInterrupt, SystemExit):
@@ -466,6 +474,9 @@ class ResilientLoop:
         self.params, self.opt_state = new_params, new_opt
         self.step += 1
         self._steps.inc()
+        if aux and self.aux_counters is not None:
+            for k, v in aux[0].items():
+                self.aux_counters[k] += int(v)
         if self.step > self._failed_step:
             self._consecutive_failures = 0   # past the failure: reset
         res = StepResult(self.step, lossf, dt,

@@ -245,3 +245,59 @@ def test_moe_model_pallas_matches_structured(quantize):
     l_p, g_p = mesp.value_and_grad(params, cfg, batch, mode="pallas")
     np.testing.assert_allclose(float(l_p), float(l_s), rtol=1e-5)
     assert _rel(g_p, g_s) <= 1e-5
+
+
+def _host_mesh():
+    """A (data, model) mesh over the one CPU device: enough for a policy
+    whose ``act_spec`` names mesh axes, which selects the expert layer's
+    capacity path (the multi-chip launchers' explicitly sharded call)."""
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+
+
+_ACT = jax.sharding.PartitionSpec("data", "model", None)
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_moe_capacity_path_pallas_matches_structured(quantize):
+    """The capacity path under an explicit ``act_spec``: pallas mode (the
+    grouped kernel over the [E, B·C] expert buffers) reproduces structured
+    mode's loss and LoRA gradients."""
+    cfg = get_config("olmoe-1b-7b").reduced()
+    params = M.init_params(jax.random.PRNGKey(0), cfg, quantize=quantize)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
+    batch = {"tokens": tokens, "labels": tokens}
+    with _host_mesh():
+        l_s, g_s = mesp.value_and_grad(params, cfg, batch, mode="structured",
+                                       act_spec=_ACT)
+        l_p, g_p = mesp.value_and_grad(params, cfg, batch, mode="pallas",
+                                       act_spec=_ACT)
+    np.testing.assert_allclose(float(l_p), float(l_s), rtol=1e-5)
+    assert _rel(g_p, g_s) <= 1e-5
+
+
+def test_moe_capacity_path_counts_dropped_slots_at_skew():
+    """A router of zeros sends every token to experts 0 and 1 (ties go to
+    the lowest ids): with capacity C = 24 rows a sequence for 32 slots,
+    the capacity path drops 8 slots per expert, sequence and layer, and
+    the dropless path, on the same batch, none."""
+    from repro.api.policy import ExecutionPolicy
+
+    cfg = get_config("olmoe-1b-7b").reduced()
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    params["blocks"]["moe"]["router"] = jnp.zeros_like(
+        params["blocks"]["moe"]["router"])
+    B, N = 2, 32
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, N), 0, cfg.vocab)
+    batch = {"tokens": tokens, "labels": tokens}
+    m, L = cfg.moe, cfg.n_layers
+    C = 24
+    with _host_mesh():
+        _, cap = M.loss_and_aux(params, cfg, batch, policy=ExecutionPolicy(
+            backend="structured", act_spec=_ACT))
+    _, drop = M.loss_and_aux(params, cfg, batch)
+    routed = B * N * m.top_k * L
+    assert int(cap["routed_rows"]) == routed == int(drop["routed_rows"])
+    assert int(cap["dropped"]) == B * 2 * (N - C) * L
+    assert int(cap["computed_rows"]) == m.n_experts * B * C * L
+    assert int(drop["dropped"]) == 0

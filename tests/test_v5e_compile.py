@@ -8,6 +8,11 @@ K = 896, N = 4864, rank 8, 14 q heads over 2 kv heads of 64, bf16) for a
 described ``v5e:2x2`` topology and checks that the compiled program runs
 the named Pallas kernels (``tpu_custom_call``).
 
+Moonlight-16B-A3B's kernels are compiled at its cell's shapes: flash at a
+q·k width of 192 against v 128 over 8192 positions and 16 heads, the
+routed-expert kernels between d 2048 and 1408 on a device-side tile
+schedule, and RMSNorm's backward over the cell's 4 × 8192 rows.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every pytest worker imports this file.
 """
@@ -126,15 +131,49 @@ def test_flash_fwd_bwd_compiles(one_chip, seq):
     _assert_kernels(text, "flash_fwd", "flash_dq", "flash_dkv")
 
 
-@pytest.mark.parametrize("rows", [M, 4 * M])
-def test_rmsnorm_vjp_compiles(one_chip, rows):
-    """One row block (batch 1) and several (batch 4 on one chip)."""
+@pytest.mark.parametrize("rows,d", [(M, K), (4 * M, K), (4 * 8192, 2048)])
+def test_rmsnorm_vjp_compiles(one_chip, rows, d):
+    """One row block (batch 1), several (batch 4 on one chip), and the
+    Moonlight cell's 4 × 8192 rows of 2048."""
     def f(x, w):
         return _grad_sum(lambda x, w: ops.rmsnorm_kernel(x, w, 1e-6, False),
                          (0, 1))(x, w)
 
-    text = _compile(f, _s(one_chip, (rows, K)), _s(one_chip, (K,)))
+    text = _compile(f, _s(one_chip, (rows, d)), _s(one_chip, (d,)))
     _assert_kernels(text, "rmsnorm_fwd", "rmsnorm_bwd")
+
+
+def test_flash_qk_192_v_128_compiles(one_chip):
+    """Latent attention's flash call at the Moonlight cell's shapes: one
+    sequence of 8192, 16 heads, q·k 192 against v 128."""
+    def f(q, k, v):
+        return _grad_sum(lambda q, k, v: ops.flash_attention(
+            q, k, v, True, 0, False, None), (0, 1, 2))(q, k, v)
+
+    text = _compile(f, _s(one_chip, (1, 16, 8192, 192)),
+                    _s(one_chip, (1, 16, 8192, 192)),
+                    _s(one_chip, (1, 16, 8192, 128)))
+    _assert_kernels(text, "flash_fwd", "flash_dq", "flash_dkv")
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_expert_kernels_compile(one_chip, k, n):
+    """The routed-expert linear (fwd, dx, dab) of a Moonlight MoE layer: 8
+    held experts, rows for 4 × 8192 tokens at 6 slots in 512-row tiles,
+    the tile schedule and live count as device arrays."""
+    policy = ExecutionPolicy(backend="pallas", interpret=False)
+    rows, bm = 4 * 8192 * 6 + 8 * 512, 512
+
+    def f(x, w, a, b, gid, live):
+        return _grad_sum(lambda x, a, b: ops.lora_expert_linear(
+            x, w, a, b, gid, live, 2.0, bm=bm, policy=policy),
+            (0, 1, 2))(x, a, b)
+
+    text = _compile(f, _s(one_chip, (rows, k)), _s(one_chip, (8, k, n)),
+                    _s(one_chip, (8, k, R)), _s(one_chip, (8, R, n)),
+                    _s(one_chip, (rows // bm,), jnp.int32),
+                    _s(one_chip, (), jnp.int32))
+    _assert_kernels(text, "expert_fwd", "expert_dx", "expert_dab")
 
 
 def test_rope_vjp_compiles(one_chip):
